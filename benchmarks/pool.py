@@ -29,7 +29,15 @@ import concurrent.futures
 import multiprocessing
 from typing import Callable, List, Sequence, Tuple
 
+import jax
+
 from repro.energy.meter import EnergyMeter
+
+
+def _cpu_only() -> None:
+    # a chip belongs to one process, and the parent holds it: a worker that
+    # initialised the TPU backend would fail or hang, so pin it to the CPU
+    jax.config.update("jax_platforms", "cpu")
 
 
 def run_cells(worker: Callable, cells: Sequence, jobs: int) -> List:
@@ -46,8 +54,8 @@ def run_cells(worker: Callable, cells: Sequence, jobs: int) -> List:
     # the time the sweep starts, and forking a multithreaded process can
     # deadlock; forkserver workers start from a clean exec'd interpreter
     ctx = multiprocessing.get_context("forkserver")
-    with concurrent.futures.ProcessPoolExecutor(max_workers=jobs,
-                                                mp_context=ctx) as ex:
+    with concurrent.futures.ProcessPoolExecutor(
+            max_workers=jobs, mp_context=ctx, initializer=_cpu_only) as ex:
         futures = {ex.submit(worker, c): i for i, c in enumerate(cells)}
         for fut in concurrent.futures.as_completed(futures):
             out[futures[fut]] = fut.result()

@@ -1,10 +1,11 @@
-"""Target-hardware constants and the chip power model.
+"""Chip constants keyed by the ``device_kind`` JAX reports, and host power.
 
-TPU v5e (the TARGET; this container is CPU-only so all TPU numbers are
-analytical): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI — the constants
-mandated for the roofline analysis.  Power bins are drawn from public v5e
-figures (TDP ~215 W) and are used by the energy estimator; they are clearly
-*derived*, never presented as measurements.
+TPU v5e peaks are Google Cloud's published figures ("TPU v5e"): 197 TFLOP/s
+bf16, 16 GB of HBM at 819 GB/s; ~50 GB/s per ICI link.  The power bins are
+derived from the public TDP (~215 W) and feed only the analytic roofline
+estimator: nothing here is a measurement, and serving still bills at the host
+constants below, on the chip as on the CPU.  A device kind missing from
+:data:`CHIPS` is an error (:func:`chip_spec`), never a default.
 """
 
 from __future__ import annotations
@@ -41,9 +42,21 @@ TPU_V5E = ChipSpec(
     power_idle_w=65.0,
 )
 
-# Host CPU used ONLY to convert measured wall-times of the small smoke models
-# into indicative joules for the serving benchmarks; flagged 'measured*' with
-# an assumed package power (no RAPL access in this container).
+# device_kind (as jax.devices()[0].device_kind reports it) -> spec
+CHIPS = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_spec(device_kind: str) -> ChipSpec:
+    """The spec of an attached chip; raises for a kind not in :data:`CHIPS`."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise KeyError(f"no ChipSpec for device kind {device_kind!r}; "
+                       f"known: {sorted(CHIPS)}") from None
+
+
+# Assumed host package power that serving bills measured step times at
+# (no RAPL access): an indicative proxy, not the chip's draw.
 HOST_CPU_POWER_W = 65.0
 
 # Idle package draw as a fraction of active draw: a provisioned endpoint that

@@ -5,7 +5,8 @@ G=H/K query rows of the GQA group attend over the cache, streamed through VMEM
 ``block_s`` keys at a time with a flash-style running (m, l, acc).  Per-request
 valid ``lengths`` and an optional sliding window bound the scan.
 
-Layouts: q (B, K, G, dh); k/v cache (B, K, S, dh); lengths (B, 1) int32.
+Layouts: q (B, K, G, dh); k/v cache (B, K, S, dh); lengths (B,) int32, held
+whole in SMEM (a (1, 1) VMEM block is below the TPU's (8, 128) tiling).
 """
 
 from __future__ import annotations
@@ -31,7 +32,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[0, 0]                              # valid cache entries
+    length = len_ref[pl.program_id(0)]                  # valid cache entries
     q = q_ref[0, 0].astype(jnp.float32) * scale         # (G, dh)
     k = k_ref[0, 0].astype(jnp.float32)                 # (bs, dh)
     s = jax.lax.dot_general(
@@ -79,7 +80,7 @@ def decode_attention(
         kernel,
         grid=(B, K, ns),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, k, si: (b, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, G, dh), lambda b, k, si: (b, k, 0, 0)),
             pl.BlockSpec((1, 1, block_s, dh), lambda b, k, si: (b, k, si, 0)),
             pl.BlockSpec((1, 1, block_s, dh), lambda b, k, si: (b, k, si, 0)),
@@ -92,4 +93,4 @@ def decode_attention(
             pltpu.VMEM((G, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(lengths.reshape(B, 1).astype(jnp.int32), q, k_cache, v_cache)
+    )(lengths.reshape(B).astype(jnp.int32), q, k_cache, v_cache)

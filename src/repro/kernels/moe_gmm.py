@@ -28,7 +28,7 @@ def _kernel(gs_ref, x_ref, w_ref, o_ref, acc_scr, *, block_c: int):
 
     x = x_ref[0].astype(jnp.float32)                     # (bc, bd)
     rows = ci * block_c + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    x = jnp.where(rows < gs_ref[0, 0], x, 0.0)
+    x = jnp.where(rows < gs_ref[pl.program_id(0)], x, 0.0)
     acc_scr[...] += jax.lax.dot(
         x, w_ref[0].astype(jnp.float32), preferred_element_type=jnp.float32
     )
@@ -55,7 +55,8 @@ def moe_gmm(
         kernel,
         grid=(E, pl.cdiv(C, block_c), pl.cdiv(F, block_f), pl.cdiv(D, block_d)),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda e, ci, fi, di: (e, 0)),
+            # whole in SMEM: a (1, 1) VMEM block is below the (8, 128) tiling
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_c, block_d), lambda e, ci, fi, di: (e, ci, di)),
             pl.BlockSpec((1, block_d, block_f), lambda e, ci, fi, di: (e, di, fi)),
         ],
@@ -65,4 +66,4 @@ def moe_gmm(
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
         interpret=interpret,
-    )(group_sizes.reshape(E, 1).astype(jnp.int32), x, w)
+    )(group_sizes.reshape(E).astype(jnp.int32), x, w)

@@ -1,9 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute via ``interpret=True`` — the
-kernel body runs in Python/XLA exactly as written, validating correctness; on
-TPU the same calls lower to Mosaic.  ``interpret`` is resolved once from the
-backend unless overridden.
+On a TPU the kernels lower to Mosaic.  On the CPU, where the tests run, they
+execute with ``interpret=True``: the kernel body runs as plain XLA, which
+checks results but says nothing about what the TPU compiler accepts or how
+fast it runs.  Any other backend is an error, never a silent interpreter.
+``interpret`` is resolved from the backend at trace time unless overridden.
 """
 
 from __future__ import annotations
@@ -21,7 +22,12 @@ from repro.kernels.rwkv6_scan import rwkv6_scan as _rwkv6
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on tpu or interpreted on cpu; "
+            f"backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",

@@ -14,8 +14,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 
-import jax
-
 from repro.configs import get_arch
 from repro.core.add import (
     Containerization,
@@ -26,7 +24,8 @@ from repro.core.add import (
     ServingInfrastructure,
 )
 from repro.energy.report import build_green_report
-from repro.models import init_params
+from repro.launch.compile_cache import use_compile_cache
+from repro.models import random_checkpoint
 from repro.serving.api import ServingSession, ServingSpec, endpoint_from_deployment
 from repro.serving.codecs import make_codec
 from repro.serving.container import generate_artifact
@@ -54,6 +53,7 @@ def main():
     ap.add_argument("--rate", type=float, default=20.0)
     ap.add_argument("--emit-artifact", action="store_true")
     ns = ap.parse_args()
+    use_compile_cache()
 
     arch = ns.arch + ("-smoke" if ns.smoke and not ns.arch.endswith("-smoke")
                       else "")
@@ -85,9 +85,10 @@ def main():
     spec = ServingSpec(endpoints=(ep_spec,), router=ns.router).validate()
     print(spec.to_json(indent=1))
 
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    # a host-side checkpoint: the deploy's registry round trip uploads the
+    # one device copy of the weights that the engine serves
     session = ServingSession()
-    session.deploy(spec, params={"m": params})
+    session.deploy(spec, params={"m": random_checkpoint(cfg, seed=0)})
     session.engine("m").warmup(dep.max_batch, 16)
     wl = synth_workload(ns.requests, 14, 6, cfg.vocab_size,
                         rate_per_s=ns.rate, seed=0)

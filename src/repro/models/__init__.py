@@ -4,4 +4,5 @@ from repro.models.transformer import (  # noqa: F401
     init_cache,
     init_params,
     prefill,
+    random_checkpoint,
 )

@@ -121,6 +121,14 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
     return params
 
 
+def random_checkpoint(cfg: ModelConfig, seed: int = 0) -> Dict[str, Any]:
+    """Random weights from ``seed``, made on the device under jit and brought
+    to the host: a checkpoint to deploy from that leaves no copy of the
+    weights on the device (the deploy uploads the one it serves)."""
+    init = jax.jit(init_params, static_argnums=0)
+    return jax.device_get(init(cfg, jax.random.PRNGKey(seed)))
+
+
 # =============================================================================
 # layer-stack iteration: scan (O(1) HLO) or python unroll (accurate HLO costs)
 # =============================================================================

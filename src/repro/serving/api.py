@@ -79,7 +79,10 @@ import json
 import math
 import os
 import tempfile
+import weakref
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+
+import jax
 
 from repro.carbon.shift import DeferralSpec
 from repro.carbon.signal import CarbonSpec
@@ -937,8 +940,9 @@ class ServingSession:
         self._endpoints: Dict[str, dict] = {}   # name -> {engine, spec}
         self._workloads: Dict[str, List[Request]] = {}
         self._hints: Dict[str, float] = {}
-        # key -> (params, engine); see _build_engine for the key contract
-        self._engine_memo: Dict[tuple, Tuple[object, Engine]] = {}
+        # key -> (weak refs to the params' leaves, engine); see
+        # _build_engine for the key contract
+        self._engine_memo: Dict[tuple, Tuple[tuple, Engine]] = {}
         # calibration caches keyed by engine object (identity hash): the
         # strong reference pins the engine so a recycled id() can never
         # attach another engine's measured step times
@@ -993,17 +997,21 @@ class ServingSession:
         served — int8 endpoints pull QTensor weights, fp32 endpoints pull
         full precision, from the same uploaded checkpoint.
 
-        The memo key includes the params' identity (and the memo entry pins
-        the params object alive), so re-deploying the same model name with
-        DIFFERENT weights rebuilds — it never silently serves the first
-        deploy's checkpoint.
+        The memo key includes the identity of the params' leaves, so
+        re-deploying the same model name with DIFFERENT weights rebuilds — it
+        never silently serves the first deploy's checkpoint.  The memo holds
+        those leaves only weakly: it never keeps a template (which may be a
+        device-resident copy of the weights) alive, and an entry whose leaves
+        have died is dropped, since their ids may be reused.
         """
-        # intentional identity memo: the key pins the params object alive,
-        # and the memo is process-local build caching — it never influences
-        # the simulated timeline, so replay determinism is unaffected
-        key = (id(template_params),                # simlint: allow(id-key)
+        leaves = jax.tree_util.tree_leaves(template_params)
+        # intentional identity memo: process-local build caching that never
+        # influences the simulated timeline, so replay determinism holds
+        key = (tuple(id(x) for x in leaves),       # simlint: allow(id-key)
                ep.model_name, ep.version, ep.format,
                ep.si, ep.arch, ep.max_seq)
+        self._engine_memo = {k: v for k, v in self._engine_memo.items()
+                             if all(r() is not None for r in v[0])}
         hit = self._engine_memo.get(key)
         if hit is not None:
             return hit[1]
@@ -1024,7 +1032,8 @@ class ServingSession:
             engine: Engine = EagerEngine(cfg, served, ep.max_seq)
         else:
             engine = CompiledEngine(cfg, served, ep.max_seq)
-        self._engine_memo[key] = (template_params, engine)
+        self._engine_memo[key] = (tuple(weakref.ref(x) for x in leaves),
+                                  engine)
         return engine
 
     def engine(self, name: str) -> Engine:

@@ -58,25 +58,19 @@ class QTensor:
         return cls(*children)
 
 
+def _key(path) -> str:
+    return "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
+
+
 def _flatten(params) -> Dict[str, np.ndarray]:
-    flat = {}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
-        key = "/".join(
-            str(getattr(p, "key", getattr(p, "idx", p))) for p in path
-        )
-        flat[key] = np.asarray(leaf)
-    return flat
+    return {_key(path): np.asarray(leaf) for path, leaf
+            in jax.tree_util.tree_flatten_with_path(params)[0]}
 
 
 def _unflatten(template, flat: Dict[str, np.ndarray]):
     paths = jax.tree_util.tree_flatten_with_path(template)[0]
     treedef = jax.tree_util.tree_structure(template)
-    leaves = []
-    for path, _ in paths:
-        key = "/".join(
-            str(getattr(p, "key", getattr(p, "idx", p))) for p in path
-        )
-        leaves.append(jnp.asarray(flat[key]))
+    leaves = [jnp.asarray(flat[_key(path)]) for path, _ in paths]
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
@@ -103,46 +97,62 @@ def load_native(template, path: str):
 # -- rsm (manifest + raw bin) ----------------------------------------------------
 
 
+# the modules whose weights layers.dense() consumes: only those may become
+# QTensor leaves.  Embeddings are gathered, routers need f32 logits, and
+# norms, biases and recurrent parameters never reach dense().
+_DENSE_MODULES = ("attn", "xattn", "mlp", "dense_mlp")
+
+
+def _quantizable(key: str, leaf) -> bool:
+    *_, module, name = ("",) + tuple(key.split("/"))
+    return (
+        module in _DENSE_MODULES
+        and name.startswith("w")
+        and leaf.ndim in (2, 3)  # (D, N), or stacked layers (L, D, N)
+        and leaf.shape[-2] >= 8
+        and str(leaf.dtype) in ("float32", "float16", "bfloat16")
+    )
+
+
+def _write(f, leaf) -> int:
+    """Write ``leaf``'s raw bytes (one host copy at most); returns the count."""
+    a = np.ascontiguousarray(np.asarray(leaf))
+    return f.write(a.reshape(-1).view(np.uint8).data)
+
+
 def save_rsm(params, path: str, quantize: bool = False) -> int:
-    """Returns total bytes on disk. ``quantize`` -> rsm_int8."""
+    """Returns total bytes on disk. ``quantize`` -> rsm_int8.
+
+    Tensors are written one at a time, each in its own dtype (bf16 stays
+    bf16), so the host holds one tensor and never the whole tree.  Stacked
+    weights quantize one (D, N) layer slice at a time: the device holds a
+    slice and its f32 temporaries, never a second copy of the weights.
+    """
     os.makedirs(path, exist_ok=True)
-    flat = _flatten(params)
     manifest = {"format": "rsm_int8" if quantize else "rsm", "tensors": {}}
     offset = 0
-    blobs = []
-    for key, arr in sorted(flat.items()):
-        quantizable = (
-            quantize
-            and arr.ndim in (2, 3)  # (D, N) or stacked-layers (L, D, N)
-            and arr.shape[-2] >= 8
-            and str(arr.dtype) in ("float32", "float16", "bfloat16")
-            # embeddings are gathered (not matmul'd) and routers need f32
-            # logits — keep them full precision
-            and not any(t in key for t in ("embed", "lm_head", "router"))
-        )
-        if quantizable:
-            wq, scales = quantize_int8(jnp.asarray(arr))
-            wq, scales = np.asarray(wq), np.asarray(scales)
-            entry = {
-                "dtype": "int8", "shape": list(arr.shape), "offset": offset,
-                "quantized": True, "scales_offset": offset + wq.nbytes,
-                "orig_dtype": str(arr.dtype),
-            }
-            blobs += [wq.tobytes(), scales.tobytes()]
-            offset += wq.nbytes + scales.nbytes
-        else:
-            a = arr.astype(np.float32) if str(arr.dtype) == "bfloat16" else arr
-            entry = {
-                "dtype": str(a.dtype), "shape": list(arr.shape),
-                "offset": offset, "quantized": False,
-                "orig_dtype": str(arr.dtype),
-            }
-            blobs.append(a.tobytes())
-            offset += a.nbytes
-        manifest["tensors"][key] = entry
+    leaves = sorted(
+        ((_key(p), leaf)
+         for p, leaf in jax.tree_util.tree_flatten_with_path(params)[0]),
+        key=lambda kv: kv[0])
     with open(os.path.join(path, "tensors.bin"), "wb") as f:
-        for b in blobs:
-            f.write(b)
+        for key, leaf in leaves:
+            entry = {"shape": list(leaf.shape), "offset": offset,
+                     "orig_dtype": str(leaf.dtype)}
+            if quantize and _quantizable(key, leaf):
+                slices = [leaf] if leaf.ndim == 2 else list(leaf)
+                scales = []
+                for w in slices:
+                    wq, sc = quantize_int8(jnp.asarray(w))
+                    offset += _write(f, wq)
+                    scales.append(np.asarray(sc))
+                entry.update(dtype="int8", quantized=True,
+                             scales_offset=offset)
+                offset += _write(f, np.stack(scales))
+            else:
+                entry.update(dtype=str(leaf.dtype), quantized=False)
+                offset += _write(f, leaf)
+            manifest["tensors"][key] = entry
     with open(os.path.join(path, "manifest.json"), "w") as f:
         json.dump(manifest, f, indent=1)
     return sum(
@@ -171,11 +181,8 @@ def load_rsm(template, path: str, as_qtensor: bool = False):
     paths = jax.tree_util.tree_flatten_with_path(template)[0]
     treedef = jax.tree_util.tree_structure(template)
     leaves = []
-    for path_keys, tmpl_leaf in paths:
-        key = "/".join(
-            str(getattr(p, "key", getattr(p, "idx", p))) for p in path_keys
-        )
-        e = manifest["tensors"][key]
+    for path_keys, _ in paths:
+        e = manifest["tensors"][_key(path_keys)]
         shape = tuple(e["shape"])
         if e["quantized"]:
             n = int(np.prod(shape))
@@ -196,7 +203,7 @@ def load_rsm(template, path: str, as_qtensor: bool = False):
                     .astype(jnp.dtype(e["orig_dtype"]))
                 )
         else:
-            dt = np.dtype(e["dtype"])
+            dt = jnp.dtype(e["dtype"])
             n = int(np.prod(shape)) if shape else 1
             arr = _own(
                 np.frombuffer(buf, dt, count=n, offset=e["offset"]).reshape(

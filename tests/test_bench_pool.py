@@ -7,6 +7,7 @@ pool-free (``jobs=1`` exercises the inline path, which is the contract the
 parallel path is pinned against elsewhere by the cell-order indexing).
 """
 
+import jax
 import pytest
 
 from benchmarks.pool import merge_meters, run_cells
@@ -23,6 +24,15 @@ def test_run_cells_serial_preserves_cell_order():
 
 def test_run_cells_empty():
     assert run_cells(_square, [], jobs=1) == []
+
+
+def _platforms(_):
+    return jax.config.jax_platforms
+
+
+def test_pool_workers_are_pinned_to_cpu():
+    """Workers never claim the chip the parent process holds."""
+    assert run_cells(_platforms, [0, 1], jobs=2) == ["cpu", "cpu"]
 
 
 def _mk_meter(active_s: float, idle_s: float) -> EnergyMeter:

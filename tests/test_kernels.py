@@ -55,6 +55,17 @@ def test_decode_attention_sweep(B, K, G, S, dh, dtype):
     )
 
 
+def test_kernels_interpret_only_on_cpu(monkeypatch):
+    """A backend that is neither the TPU nor the CPU is an error, never a
+    silent interpreter."""
+    assert ops._default_interpret() is True       # the tests run on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="neither"):
+        ops._default_interpret()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._default_interpret() is False
+
+
 def test_decode_attention_window():
     B, K, G, S, dh = 2, 2, 2, 128, 32
     q = jax.random.normal(KEY(6), (B, K, G, dh))

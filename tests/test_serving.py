@@ -161,6 +161,45 @@ def test_int8_qtensor_serving(setup, tmp_path):
     assert corr > 0.99, corr
 
 
+def test_rsm_roundtrip_keeps_bf16(setup, tmp_path):
+    """bf16 weights go through the registry as bf16: same dtype, same
+    values, and two bytes per parameter on disk."""
+    cfg, params = setup
+    bf16 = jax.tree.map(lambda x: np.asarray(x, jnp.bfloat16), params)
+    formats.save_rsm(bf16, str(tmp_path / "rsm"))
+    # a shape-only template: every value must come from the file
+    back = formats.load_rsm(jax.eval_shape(lambda: bf16), str(tmp_path / "rsm"))
+    for a, b in zip(jax.tree.leaves(bf16), jax.tree.leaves(back)):
+        assert b.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    on_disk = os.path.getsize(tmp_path / "rsm" / "tensors.bin")
+    assert on_disk == sum(x.nbytes for x in jax.tree.leaves(bf16))
+
+
+def test_int8_quantizes_only_dense_weights(tmp_path):
+    """With 8+ stacked layers the per-layer norms are (L, D) matrices too;
+    only the weights dense() consumes may become QTensor leaves, or the
+    layer scan gets mismatched leading axes."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_arch(ARCH), num_layers=8)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    formats.save_rsm(params, str(tmp_path / "q"), quantize=True)
+    pq = formats.load_rsm(params, str(tmp_path / "q"), as_qtensor=True)
+    quantized = sorted(
+        formats._key(p) for p, leaf in jax.tree_util.tree_flatten_with_path(
+            pq, is_leaf=lambda x: isinstance(x, formats.QTensor))[0]
+        if isinstance(leaf, formats.QTensor))
+    assert quantized == [f"layers/{m}/{w}" for m, w in (
+        ("attn", "wk"), ("attn", "wo"), ("attn", "wq"), ("attn", "wv"),
+        ("mlp", "wi_gate"), ("mlp", "wi_up"), ("mlp", "wo"))]
+    tokens = jnp.asarray(np.arange(8, dtype=np.int32)[None])
+    full, _ = CompiledEngine(cfg, params, 16)._prefill(tokens)
+    q, _ = CompiledEngine(cfg, pq, 16)._prefill(tokens)
+    assert np.corrcoef(np.asarray(full).ravel(),
+                       np.asarray(q).ravel())[0, 1] > 0.99
+
+
 def test_cloud_service(setup, tmp_path):
     cfg, params = setup
     cloud = CloudService(str(tmp_path / "registry"))

@@ -449,6 +449,28 @@ def test_engine_memo_shared_across_deploys(tmp_path, smoke_params):
     assert not np.allclose(np.asarray(a), np.asarray(b))
 
 
+def test_engine_memo_does_not_pin_the_template(tmp_path):
+    """The memo holds the template's leaves weakly: once the caller drops
+    the template, no copy of it stays alive in the session."""
+    import gc
+    import weakref
+
+    import jax
+
+    from repro.configs import get_arch
+    from repro.models import init_params
+
+    params = init_params(get_arch(ARCH), jax.random.PRNGKey(3))
+    leaf = weakref.ref(jax.tree.leaves(params)[0])
+    session = ServingSession(registry_root=str(tmp_path / "reg"))
+    spec = ServingSpec(endpoints=(
+        EndpointSpec(name="m", arch=ARCH, format="rsm", max_seq=64),))
+    session.deploy(spec, params={"m": params})
+    del params
+    gc.collect()
+    assert leaf() is None
+
+
 def test_calibrate_skips_measured_shapes():
     """Two endpoints sharing one engine (or repeated sweep cells) pay for
     exactly one calibration — already-measured shapes are not re-run."""
