@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.configs import ModelConfig
 from repro.models import transformer
+from repro.serving.telemetry.host import span
 
 
 def token_landing_s(prefill_s: float, decode_s: float, n_steps: int,
@@ -47,7 +48,6 @@ class GenerationResult:
     prefill_s: float
     decode_s: float               # total decode wall time
     n_steps: int
-    compile_s: float = 0.0
 
     @property
     def decode_s_per_token(self) -> float:
@@ -149,7 +149,9 @@ class CompiledEngine(Engine):
     def __init__(self, cfg: ModelConfig, params, max_seq: int = 256,
                  donate_cache: bool = True):
         super().__init__(cfg, params, max_seq)
-        self._compiled: Dict[Tuple, object] = {}
+        # (entry, input shape) -> calls; a key's first call compiles (or
+        # loads from the persistent cache), inside a ``serve.compile`` span
+        self._compiled: Dict[Tuple[str, tuple], int] = {}
 
         def prefill_fn(params, batch):
             return transformer.prefill(params, cfg, batch, max_seq)
@@ -172,12 +174,23 @@ class CompiledEngine(Engine):
                                         cfg.jnp_dtype)
         return batch
 
+    def _call(self, entry: str, shape: tuple, fn, *args):
+        key = (entry, tuple(shape))
+        n = self._compiled.get(key, 0)
+        self._compiled[key] = n + 1
+        if n:
+            return fn(*args)
+        with span("serve.compile", entry=entry, shape=str(key[1])):
+            return fn(*args)
+
     def _prefill(self, tokens):
         batch = {"tokens": tokens, **self._extra_inputs(*tokens.shape)}
-        return self._prefill_jit(self.params, batch)
+        return self._call("prefill", tokens.shape, self._prefill_jit,
+                          self.params, batch)
 
     def _decode(self, cache, tokens):
-        return self._decode_jit(self.params, cache, tokens)
+        return self._call("decode", tokens.shape, self._decode_jit,
+                          self.params, cache, tokens)
 
     def warmup(self, batch: int, prompt_len: int) -> float:
         """AOT-compile the (batch, prompt_len) shapes; returns compile seconds.

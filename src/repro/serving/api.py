@@ -79,6 +79,7 @@ import json
 import math
 import os
 import tempfile
+import time
 import weakref
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
@@ -122,6 +123,7 @@ from repro.serving.telemetry import (
     TelemetrySpec,
     TraceRecorder,
     phase_breakdown,
+    span,
 )
 from repro.workload.calendar import TrafficCalendar
 from repro.workload.generators import WorkloadSpec
@@ -948,6 +950,10 @@ class ServingSession:
         # attach another engine's measured step times
         self._cal: Dict[Engine, StepTimeCache] = {}
         self.spec: Optional[ServingSpec] = None
+        # host seconds of the last deploy's registry round trip, summed over
+        # the engines it built: ``save`` writes the format, ``load`` reads
+        # it back onto the device
+        self.deploy_phases_s: Dict[str, float] = {"save": 0.0, "load": 0.0}
 
     # -- deploy ---------------------------------------------------------------
     def deploy(self, spec: ServingSpec, *,
@@ -966,6 +972,7 @@ class ServingSession:
         """
         spec.validate()
         self.spec = spec
+        self.deploy_phases_s = {"save": 0.0, "load": 0.0}
         self._endpoints = {}
         self._workloads = {}
         self._hints = {}
@@ -1020,14 +1027,25 @@ class ServingSession:
         cfg = get_arch(ep.arch)
         path = os.path.join(self._registry(),
                             f"{ep.model_name}-v{ep.version}.{ep.format}")
-        if ep.format == "native":
-            formats.save_native(template_params, path)
-            served = formats.load_native(template_params, path)
-        else:
-            formats.save_rsm(template_params, path,
-                             quantize=(ep.format == "rsm_int8"))
-            served = formats.load_rsm(template_params, path,
-                                      as_qtensor=(ep.format == "rsm_int8"))
+        t0 = time.perf_counter()                  # simlint: allow(wall-clock)
+        with span("serve.deploy.save", format=ep.format):
+            if ep.format == "native":
+                formats.save_native(template_params, path)
+            else:
+                formats.save_rsm(template_params, path,
+                                 quantize=(ep.format == "rsm_int8"))
+        t1 = time.perf_counter()                  # simlint: allow(wall-clock)
+        with span("serve.deploy.load", format=ep.format):
+            if ep.format == "native":
+                served = formats.load_native(template_params, path)
+            else:
+                served = formats.load_rsm(
+                    template_params, path,
+                    as_qtensor=(ep.format == "rsm_int8"))
+            jax.block_until_ready(served)
+        t2 = time.perf_counter()                  # simlint: allow(wall-clock)
+        self.deploy_phases_s["save"] += t1 - t0
+        self.deploy_phases_s["load"] += t2 - t1
         if ep.si == "si1_no_runtime":
             engine: Engine = EagerEngine(cfg, served, ep.max_seq)
         else:

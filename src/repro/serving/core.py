@@ -128,6 +128,13 @@ class SchedulerCore:
         self.wall = 0.0
         self.responses: List[Response] = []
         self.total_tokens = 0
+        # the served path's counts, in plain ints where the work happens
+        # (``ContinuousBatchPolicy`` keeps them): decode steps, live slots
+        # summed over steps, slots summed over steps (occupancy is the
+        # ratio of the two), host reads of device values, admissions
+        self.counters: Dict[str, int] = dict.fromkeys(
+            ("decode_steps", "live_slot_steps", "slot_steps", "d2h",
+             "admissions"), 0)
         # new_meter returns the conservation-auditing wrapper when
         # REPRO_SANITIZE=1 (see repro.energy.sanitize), the plain meter
         # otherwise

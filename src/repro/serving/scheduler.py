@@ -38,6 +38,7 @@ from repro.energy.meter import estimate_j_per_token
 from repro.serving.core import SchedulerCore, SchedulingPolicy, pad_prompts
 from repro.serving.request import Request, ServingMetrics
 from repro.serving.stepcache import StepTimeCache, shape_bucket, synth_tokens
+from repro.serving.telemetry.host import span
 
 # backwards-compatible alias (pre-core name)
 _pad_prompts = pad_prompts
@@ -232,90 +233,119 @@ class ContinuousBatchPolicy(SchedulingPolicy):
         return jax.tree.map(put, cache, sub)
 
     def _admit(self, core: SchedulerCore) -> None:
-        for s in range(self.num_slots):
-            if self.slot_req[s] is not None:
-                continue
-            nxt = core.peek()
-            if nxt is None or nxt.arrival_s > core.now:
-                return
-            req = core.pop_next(core.now)   # most urgent arrived request
-            # bucket prompt length to a power of two so the compiled prefill
-            # executable (and its measured duration) is reused across requests
-            S = len(req.prompt)
-            bucket = shape_bucket(S)
-            prompt = np.zeros((bucket,), np.int32)
-            prompt[:S] = req.prompt
+        with span("serve.admit"):
+            for s in range(self.num_slots):
+                if self.slot_req[s] is None and not self._admit_one(core, s):
+                    return
 
-            def thunk():
-                # sanctioned measurement closure: a step-cache MISS really
-                # executes the engine, and the measured duration is what the
-                # virtual clock replays from then on
-                t0 = time.perf_counter()          # simlint: allow(wall-clock)
+    def _admit_one(self, core: SchedulerCore, s: int) -> bool:
+        """Admit the most urgent arrived request into free slot ``s``;
+        False when none has arrived."""
+        nxt = core.peek()
+        if nxt is None or nxt.arrival_s > core.now:
+            return False
+        req = core.pop_next(core.now)   # most urgent arrived request
+        # bucket prompt length to a power of two so the compiled prefill
+        # executable (and its measured duration) is reused across requests
+        S = len(req.prompt)
+        bucket = shape_bucket(S)
+        prompt = np.zeros((bucket,), np.int32)
+        prompt[:S] = req.prompt
+
+        def thunk():
+            # sanctioned measurement closure: a step-cache MISS really
+            # executes the engine, and the measured duration is what the
+            # virtual clock replays from then on
+            t0 = time.perf_counter()          # simlint: allow(wall-clock)
+            with span("serve.prefill", rid=req.rid, bucket=bucket, tokens=S):
                 logits, sub = core.engine.prefill_one(prompt[None, :])
                 tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            with span("serve.prefill.wait", rid=req.rid):
                 tok.block_until_ready()
-                dt = time.perf_counter() - t0     # simlint: allow(wall-clock)
-                return (dt,), (tok, sub)
+            dt = time.perf_counter() - t0     # simlint: allow(wall-clock)
+            return (dt,), (tok, sub)
 
-            (dt,), out = core.timed(("prefill1", bucket), thunk)
-            start = core.now
+        (dt,), out = core.timed(("prefill1", bucket), thunk)
+        start = core.now
+        with span("serve.bill"):
             core.advance_active(dt, rids=[req.rid], tokens=1)
-            self.slot_synth[s] = out is None
-            if out is not None:
-                tok, sub = out
+        core.counters["admissions"] += 1
+        self.slot_synth[s] = out is None
+        if out is not None:
+            tok, sub = out
+            with span("serve.insert", rid=req.rid, slot=s):
                 self.kv = self._insert(self.kv, sub, s)
                 self.cur_tok = self.cur_tok.at[s].set(tok[0])
+            with span("serve.first_token", rid=req.rid):
                 first = int(tok[0])
-            else:
-                first = int(synth_tokens(req.prompt, 1, core.vocab)[0])
-            self.slot_req[s] = req
-            self.slot_emitted[s] = 1
-            self.slot_tokens[s] = [first]
-            self.slot_start[s] = start
-            self.slot_ttft[s] = core.now
+            core.counters["d2h"] += 1
+        else:
+            first = int(synth_tokens(req.prompt, 1, core.vocab)[0])
+        self.slot_req[s] = req
+        self.slot_emitted[s] = 1
+        self.slot_tokens[s] = [first]
+        self.slot_start[s] = start
+        self.slot_ttft[s] = core.now
+        return True
 
     def step(self, core: SchedulerCore) -> None:
+        with span("serve.step",
+                  live=sum(r is not None for r in self.slot_req)):
+            self._step(core)
+
+    def _step(self, core: SchedulerCore) -> None:
         self._admit(core)
         if not self.active(core):
             nxt = core.peek()
             if nxt is not None:
                 core.advance_to(nxt.arrival_s)   # idle until next arrival
             return
+        rids = [r.rid for r in self.slot_req if r is not None]
 
         def thunk():
             # sanctioned measurement closure (see the prefill thunk above)
             t0 = time.perf_counter()              # simlint: allow(wall-clock)
-            logits, kv = core.engine.decode_batch(self.kv, self.cur_tok)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
-            tok.block_until_ready()
+            with span("serve.decode", live=len(rids)):
+                logits, kv = core.engine.decode_batch(self.kv, self.cur_tok)
+                tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            with span("serve.decode.wait"):
+                tok.block_until_ready()
             dt = time.perf_counter() - t0         # simlint: allow(wall-clock)
             return (dt,), (tok, kv)
 
         (dt,), out = core.timed(("decode", self.num_slots), thunk)
-        rids = [r.rid for r in self.slot_req if r is not None]
-        core.advance_active(dt, rids=rids, tokens=len(rids))
+        with span("serve.bill"):
+            core.advance_active(dt, rids=rids, tokens=len(rids))
+        counters = core.counters
+        counters["decode_steps"] += 1
+        counters["live_slot_steps"] += len(rids)
+        counters["slot_steps"] += self.num_slots
         if out is not None:
             tok, self.kv = out
             self.cur_tok = tok
-        for s in range(self.num_slots):
-            req = self.slot_req[s]
-            if req is None:
-                continue
-            if out is not None and not self.slot_synth[s]:
-                nxt_tok = int(np.asarray(tok[s]))
-            else:
-                nxt_tok = int(
-                    synth_tokens(req.prompt, self.slot_emitted[s] + 1,
-                                 core.vocab)[-1]
-                )
-            self.slot_emitted[s] += 1
-            self.slot_tokens[s].append(nxt_tok)
-            if self.slot_emitted[s] >= req.max_new_tokens:
-                core.record_response(
-                    req, self.slot_tokens[s][: req.max_new_tokens],
-                    self.slot_start[s], self.slot_ttft[s], core.now,
-                )
-                self.slot_req[s] = None
+        with span("serve.readback", live=len(rids)):
+            for s in range(self.num_slots):
+                req = self.slot_req[s]
+                if req is None:
+                    continue
+                if out is not None and not self.slot_synth[s]:
+                    with span("serve.d2h", slot=s, rid=req.rid):
+                        nxt_tok = int(np.asarray(tok[s]))
+                    counters["d2h"] += 1
+                else:
+                    nxt_tok = int(
+                        synth_tokens(req.prompt, self.slot_emitted[s] + 1,
+                                     core.vocab)[-1]
+                    )
+                self.slot_emitted[s] += 1
+                self.slot_tokens[s].append(nxt_tok)
+                if self.slot_emitted[s] >= req.max_new_tokens:
+                    with span("serve.retire", rid=req.rid):
+                        core.record_response(
+                            req, self.slot_tokens[s][: req.max_new_tokens],
+                            self.slot_start[s], self.slot_ttft[s], core.now,
+                        )
+                        self.slot_req[s] = None
 
 
 # -- disaggregated phase policies (repro.serving.admission.disagg) -------------

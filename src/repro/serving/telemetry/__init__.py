@@ -15,7 +15,10 @@ lifetime* the joules, grams and milliseconds went.  This package adds:
     gauges — all stamped in virtual time, all observer-pure;
   * :mod:`~repro.serving.telemetry.export` — lossless Chrome/Perfetto
     ``trace_event`` JSON export, a trace schema validator, and the
-    per-SLO-class phase-breakdown table the report embeds.
+    per-SLO-class phase-breakdown table the report embeds;
+  * :func:`~repro.serving.telemetry.host.span` — the served path's host
+    spans (``serve.*``) on the profiler's wall clock, beside the device's
+    operations in a ``jax.profiler`` trace; never in the recorder.
 
 The reconciliation contract: span-attributed joules AND grams equal the
 meter's ``active + idle + preempt + xfer + lost`` buckets — enforced after
@@ -28,6 +31,7 @@ from repro.serving.telemetry.export import (
     validate_trace,
     write_trace,
 )
+from repro.serving.telemetry.host import span
 from repro.serving.telemetry.recorder import MetricsRegistry, TraceRecorder
 from repro.serving.telemetry.spec import TelemetrySpec
 
@@ -36,6 +40,7 @@ __all__ = [
     "TelemetrySpec",
     "TraceRecorder",
     "phase_breakdown",
+    "span",
     "to_perfetto",
     "validate_trace",
     "write_trace",
