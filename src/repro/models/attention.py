@@ -256,12 +256,18 @@ def decode_attention(
     lengths,
     *,
     window: Optional[int] = None,
+    k_new=None,
+    v_new=None,
     block_kv: int = 1024,  # kept for API compat; direct path ignores it
 ):
     """Single-token attention over a KV cache.
 
-    q: (B, H, dh); k_cache/v_cache: (B, S, K, dh); lengths: (B,) — number of
-    valid cache entries INCLUDING the current token's kv (already written).
+    q: (B, H, dh); k_cache/v_cache: (B, S, K, dh), only read; lengths: (B,)
+    — number of valid cache entries.  The query sits at position
+    ``lengths``; ``window`` keeps the cache entries in (lengths - window,
+    lengths).  ``k_new``/``v_new`` ((B, K, dh), in the cache dtype) are the
+    query token's own kv: their score and value join the cache's in one
+    softmax.
 
     Uses the DIRECT (non-chunked) softmax: the (B, K, G, S) score tensor for
     one query token is small, and the un-chunked einsum lets GSPMD implement
@@ -280,15 +286,29 @@ def decode_attention(
         "bkgd,btkd->bkgt", qf, k_cache, preferred_element_type=jnp.float32
     )  # (B, K, G, S)
     k_pos = jnp.arange(S, dtype=jnp.int32)[None, :]
-    mask = k_pos < lengths.astype(jnp.int32)[:, None]
+    n = lengths.astype(jnp.int32)[:, None]
+    mask = k_pos < n
     if window is not None:
-        mask = mask & (k_pos > (lengths.astype(jnp.int32)[:, None] - 1 - window))
+        mask = mask & (k_pos > n - window)
     s = jnp.where(mask[:, None, None, :], s, NEG_INF)
     m = s.max(axis=-1, keepdims=True)
+    if k_new is not None:
+        s_new = jnp.einsum("bkgd,bkd->bkg", qf, k_new,
+                           preferred_element_type=jnp.float32)[..., None]
+        m = jnp.maximum(m, s_new)
     p = jnp.where(mask[:, None, None, :], jnp.exp(s - m), 0.0)
     l = p.sum(axis=-1, keepdims=True)
+    if k_new is not None:
+        p_new = jnp.exp(s_new - m)
+        l = l + p_new
+    l = jnp.maximum(l, 1e-20)
     o = jnp.einsum(
-        "bkgt,btkd->bkgd", (p / jnp.maximum(l, 1e-20)).astype(v_cache.dtype),
+        "bkgt,btkd->bkgd", (p / l).astype(v_cache.dtype),
         v_cache, preferred_element_type=jnp.float32,
     )
+    if k_new is not None:
+        o = o + jnp.einsum(
+            "bkg,bkd->bkgd", (p_new / l)[..., 0].astype(v_new.dtype), v_new,
+            preferred_element_type=jnp.float32,
+        )
     return o.reshape(B, H, dh).astype(q.dtype)

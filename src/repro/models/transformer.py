@@ -178,12 +178,15 @@ def _self_attention_full(p, cfg, x, angles, *, causal=True, window=None):
     return layers.dense(o.reshape(B, S, -1), p["wo"]), (k, v)
 
 
-def _self_attention_decode(p, cfg, x, angles, kc, vc, lengths, *, window=None,
-                           uniform: bool = False):
-    """One-token self attention against a cache.
+def _self_attention_decode(p, cfg, x, angles, kc, vc, lengths, *, window=None):
+    """One-token self attention against a read-only cache.
 
-    x: (B, 1, D); kc/vc: (B, Smax, K, hd); lengths: (B,) BEFORE this token.
-    Returns (out (B,1,D), kc, vc) with the new kv written at ``lengths``.
+    x: (B, 1, D); kc/vc: (B, Smax, K, hd) holding the tokens BEFORE this one;
+    lengths: (B,) their count.  Returns (out (B,1,D), k_new, v_new): this
+    token's k/v, (B, K, hd) in the cache dtype.  Attention joins them to the
+    cache's entries in one softmax; the cache itself is not written here —
+    ``decode_step`` writes every layer's k_new/v_new at ``lengths`` once,
+    after the layer loop (``_append_kv``).
 
     When a sliding window is active and much smaller than the cache, only the
     last ``window`` cache entries are gathered and attended — decode compute
@@ -192,39 +195,41 @@ def _self_attention_decode(p, cfg, x, angles, kc, vc, lengths, *, window=None,
     B = x.shape[0]
     S = kc.shape[1]
     q, k, v = _qkv(p, cfg, x, angles)  # k,v: (B,1,K,hd)
-    if uniform:
-        # lockstep decode pool: all slots share one position -> a scalar
-        # dynamic-update-slice, which GSPMD partitions on a sharded sequence
-        # dim WITHOUT the f32 set->add scatter rewrite (2x write traffic)
-        pos = lengths[0]
-        kc = jax.lax.dynamic_update_slice(
-            kc, k.astype(kc.dtype), (0, pos, 0, 0)
-        )
-        vc = jax.lax.dynamic_update_slice(
-            vc, v.astype(vc.dtype), (0, pos, 0, 0)
-        )
-    else:
-        bidx = jnp.arange(B)
-        kc = kc.at[bidx, lengths].set(
-            k[:, 0].astype(kc.dtype), unique_indices=True,
-            mode="promise_in_bounds",
-        )
-        vc = vc.at[bidx, lengths].set(
-            v[:, 0].astype(vc.dtype), unique_indices=True,
-            mode="promise_in_bounds",
-        )
+    k_new, v_new = k[:, 0].astype(kc.dtype), v[:, 0].astype(vc.dtype)
     if window is not None and S > 2 * window:
-        new_len = lengths + 1
-        start = jnp.maximum(new_len - window, 0)                  # (B,)
+        start = jnp.maximum(lengths + 1 - window, 0)              # (B,)
         idx = start[:, None] + jnp.arange(window, dtype=jnp.int32)[None, :]
         idx = jnp.minimum(idx, S - 1)
         kw = jnp.take_along_axis(kc, idx[:, :, None, None], axis=1)
         vw = jnp.take_along_axis(vc, idx[:, :, None, None], axis=1)
-        eff_len = jnp.minimum(new_len, window)
-        o = decode_attention(q[:, 0], kw, vw, eff_len, window=None)
+        o = decode_attention(q[:, 0], kw, vw, lengths - start,
+                             k_new=k_new, v_new=v_new)
     else:
-        o = decode_attention(q[:, 0], kc, vc, lengths + 1, window=window)
-    return layers.dense(o.reshape(B, 1, -1), p["wo"]), kc, vc
+        o = decode_attention(q[:, 0], kc, vc, lengths, window=window,
+                             k_new=k_new, v_new=v_new)
+    return layers.dense(o.reshape(B, 1, -1), p["wo"]), k_new, v_new
+
+
+def _append_kv(cache, k_new, v_new, lengths, uniform: bool):
+    """Write every layer's new k/v, (L, B, K, hd), into the (L, B, S, K, hd)
+    slabs at each slot's ``lengths``: one in-place update of the donated
+    cache after the layer loop.  Returns the cache with lengths += 1.
+
+    uniform=True (every slot at one position) writes with a scalar
+    dynamic-update-slice, which GSPMD partitions on a sharded sequence dim
+    without the f32 set->add scatter rewrite (2x write traffic).
+    """
+    if uniform:
+        at = (0, 0, lengths[0], 0, 0)
+        k = jax.lax.dynamic_update_slice(cache["k"], k_new[:, :, None], at)
+        v = jax.lax.dynamic_update_slice(cache["v"], v_new[:, :, None], at)
+    else:
+        bidx = jnp.arange(lengths.shape[0])
+        k = cache["k"].at[:, bidx, lengths].set(
+            k_new, unique_indices=True, mode="promise_in_bounds")
+        v = cache["v"].at[:, bidx, lengths].set(
+            v_new, unique_indices=True, mode="promise_in_bounds")
+    return dict(cache, k=k, v=v, lengths=lengths + 1)
 
 
 def _cross_attention(p, cfg, x, enc_k, enc_v):
@@ -575,9 +580,13 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions=None,
     tokens: (B,) int32 (the previously sampled token). Returns
     (logits (B, V) f32, updated cache with lengths += 1).
 
+    The layer loop only reads the K/V slabs and emits each layer's new k/v;
+    ``_append_kv`` writes them into the (donated) slabs once, after the loop,
+    so no slab passes through the loop's outputs.
+
     uniform_lengths=True promises every slot is at the same position
-    (lockstep decode pools / the dry-run serve_step): cache writes become
-    scalar dynamic-update-slices, which partition cleanly.
+    (lockstep decode pools / the dry-run serve_step): the write becomes a
+    scalar dynamic-update-slice, which partitions cleanly.
     """
     lengths = cache["lengths"]
     B = tokens.shape[0]
@@ -605,21 +614,20 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions=None,
 
         def body(x, inp):
             lp, kc, vc = inp
-            h, kc, vc = _self_attention_decode(
+            h, kn, vn = _self_attention_decode(
                 lp["attn"], cfg,
                 layers.rms_norm(x, lp["ln1"], cfg.norm_eps),
                 angles, kc, vc, lengths, window=window,
-                uniform=uniform_lengths,
             )
             x = x + h
             h, _ = _ffn(lp, cfg, layers.rms_norm(x, lp["ln2"], cfg.norm_eps))
-            return x + h, (kc, vc)
+            return x + h, (kn, vn)
 
-        x, (kcs, vcs) = _scan_layers(
+        x, (kn, vn) = _scan_layers(
             body, x, (params["layers"], cache["k"], cache["v"]),
             unroll=cfg.unroll_layers,
         )
-        cache = dict(cache, k=kcs, v=vcs, lengths=lengths + 1)
+        cache = _append_kv(cache, kn, vn, lengths, uniform_lengths)
         return _lm_logits(params, cfg, x)[:, 0], cache
 
     if cfg.family == "ssm":
@@ -665,17 +673,16 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions=None,
 
             x, (ncs, nhs) = _scan_layers(mamba_body, x, (mp, conv_g, ssm_g), unroll=cfg.unroll_layers)
             xg = x * gain
-            h, kc, vc = _self_attention_decode(
+            h, kn, vn = _self_attention_decode(
                 shared["attn"], cfg,
                 layers.rms_norm(xg, shared["ln1"], cfg.norm_eps),
                 angles, kc, vc, lengths, window=cfg.attn_window,
-                uniform=uniform_lengths,
             )
             y = xg + h
             h, _ = _ffn(shared, cfg, layers.rms_norm(y, shared["ln2"], cfg.norm_eps))
-            return y + h, (ncs, nhs, kc, vc)
+            return y + h, (ncs, nhs, kn, vn)
 
-        x, (ncs, nhs, kcs, vcs) = _scan_layers(
+        x, (ncs, nhs, kn, vn) = _scan_layers(
             group_body, x,
             (mamba_stacked, params["group_gain"], conv, ssm_st,
              cache["k"], cache["v"]),
@@ -686,8 +693,8 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions=None,
             cache,
             conv=ncs.reshape(L, *ncs.shape[2:]).astype(cache["conv"].dtype),
             ssm=nhs.reshape(L, *nhs.shape[2:]),
-            k=kcs, v=vcs, lengths=lengths + 1,
         )
+        cache = _append_kv(cache, kn, vn, lengths, uniform_lengths)
         return _lm_logits(params, cfg, x)[:, 0], cache
 
     if cfg.family == "audio":
@@ -696,11 +703,10 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions=None,
 
         def body(x, inp):
             lp, kc, vc, xk, xv = inp
-            h, kc, vc = _self_attention_decode(
+            h, kn, vn = _self_attention_decode(
                 lp["attn"], cfg,
                 layers.rms_norm(x, lp["ln1"], cfg.norm_eps),
                 None, kc, vc, lengths, window=None,
-                uniform=uniform_lengths,
             )
             x = x + h
             q, _, _ = _qkv(lp["xattn"], cfg,
@@ -711,15 +717,15 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions=None,
             )
             x = x + layers.dense(o.reshape(x.shape[0], 1, -1), lp["xattn"]["wo"])
             h, _ = _ffn(lp, cfg, layers.rms_norm(x, lp["ln2"], cfg.norm_eps))
-            return x + h, (kc, vc)
+            return x + h, (kn, vn)
 
-        x, (kcs, vcs) = _scan_layers(
+        x, (kn, vn) = _scan_layers(
             body, x,
             (params["dec_layers"], cache["k"], cache["v"], cache["xk"],
              cache["xv"]),
             unroll=cfg.unroll_layers,
         )
-        cache = dict(cache, k=kcs, v=vcs, lengths=lengths + 1)
+        cache = _append_kv(cache, kn, vn, lengths, uniform_lengths)
         return _lm_logits(params, cfg, x)[:, 0], cache
 
     raise ValueError(cfg.family)

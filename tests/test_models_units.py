@@ -224,3 +224,56 @@ def test_sliding_window_decode_slices_cache():
     l2, _ = decode_step(params, cfg, cache2, tok)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), atol=2e-3,
                                rtol=2e-3)
+
+
+@pytest.mark.parametrize("case", ["ragged", "uniform", "window"])
+def test_decode_writes_each_slot_in_place(case):
+    """Four slots prefilled to their own lengths and inserted as continuous
+    batching inserts them: each decode step matches a full forward over the
+    slot's tokens, and writes exactly the new k/v at the slot's old length,
+    leaving every other cache entry as it was."""
+    from repro.models import decode_step, init_cache, prefill
+
+    if case == "window":  # max_seq 64 > 2 * window: the gathered-window path
+        cfg = dataclasses.replace(smoke_variant(get_arch("mixtral-8x7b")),
+                                  attn_window=8)
+        max_seq = 64
+    else:
+        cfg, max_seq = smoke_variant(get_arch("qwen3-8b")), 32
+    lens = [7, 7, 7, 7] if case == "uniform" else [5, 12, 3, 9]
+    params = init_params(cfg, KEY(29))
+    cache = init_cache(cfg, len(lens), max_seq)
+    seqs = []
+    for slot, n in enumerate(lens):
+        prompt = jax.random.randint(KEY(30 + slot), (1, n), 0, cfg.vocab_size)
+        lg, sub = prefill(params, cfg, {"tokens": prompt}, max_seq)
+        cache = jax.tree.map(
+            lambda leaf, s: (leaf.at[slot].set(s[0]) if leaf.ndim == 1
+                             else leaf.at[:, slot].set(s[:, 0])),
+            cache, sub)
+        seqs.append([int(t) for t in prompt[0]] + [int(jnp.argmax(lg[0]))])
+
+    for _ in range(3):
+        old = cache
+        logits, cache = decode_step(
+            params, cfg, old, jnp.asarray([s[-1] for s in seqs], jnp.int32),
+            uniform_lengths=case == "uniform")
+        for b, seq in enumerate(seqs):
+            n = len(seq) - 1          # the cache holds all but the last token
+            assert int(old["lengths"][b]) == n
+            assert int(cache["lengths"][b]) == n + 1
+            ref_logits, ref = prefill(params, cfg,
+                                      {"tokens": jnp.asarray(seq)[None]},
+                                      max_seq)
+            np.testing.assert_allclose(np.asarray(logits[b]),
+                                       np.asarray(ref_logits[0]),
+                                       atol=2e-2, rtol=2e-2)
+            for name in ("k", "v"):
+                was = np.asarray(old[name][:, b], np.float32)
+                now = np.asarray(cache[name][:, b], np.float32)
+                np.testing.assert_allclose(
+                    now[:, n], np.asarray(ref[name][:, 0, n], np.float32),
+                    atol=2e-2, rtol=2e-2)
+                np.testing.assert_array_equal(np.delete(now, n, axis=1),
+                                              np.delete(was, n, axis=1))
+        seqs = [s + [int(t)] for s, t in zip(seqs, jnp.argmax(logits, -1))]

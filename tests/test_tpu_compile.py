@@ -11,6 +11,7 @@ this file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +52,10 @@ def one_chip():
 
 def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on_chip(sharding, tree):
+    return jax.tree.map(lambda s: _shape(sharding, s.shape, s.dtype), tree)
 
 
 def _compile(fn, *args):
@@ -97,11 +102,7 @@ def test_minitron_4b_step_fits_one_chip(one_chip, step):
     cfg = get_arch("minitron-4b")
     B, S, max_seq = 8, 16, 256
 
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda s: _shape(one_chip, s.shape, s.dtype), tree)
-
-    params = on_chip(jax.eval_shape(
+    params = _on_chip(one_chip, jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0))))
     if step == "prefill":
         fn = jax.jit(lambda p, t: prefill(p, cfg, {"tokens": t}, max_seq))
@@ -109,10 +110,40 @@ def test_minitron_4b_step_fits_one_chip(one_chip, step):
     else:
         fn = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t),
                      donate_argnums=(1,))
-        cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, B, max_seq)))
+        cache = _on_chip(one_chip, jax.eval_shape(
+            lambda: init_cache(cfg, B, max_seq)))
         args = (params, cache, _shape(one_chip, (B,), jnp.int32))
     mem = fn.lower(*args).compile().memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 8e9     # the weights are all there
     assert total < V5E_HBM_BYTES, total
+
+
+def test_minitron_4b_decode_writes_kv_in_place(one_chip):
+    """The donated decode step at 8 slots of 2048 positions writes each new
+    token's k/v into the donated slab: no second slab in temporaries, and no
+    slab-sized copy or dynamic-update-slice."""
+    cfg = get_arch("minitron-4b")
+    B, max_seq = 8, 2048
+
+    params = _on_chip(one_chip, jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = _on_chip(one_chip, jax.eval_shape(
+        lambda: init_cache(cfg, B, max_seq)))
+    fn = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t),
+                 donate_argnums=(1,))
+    compiled = fn.lower(params, cache,
+                        _shape(one_chip, (B,), jnp.int32)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 64 * 2**20, temp
+    slab = "bf16[%s]" % ",".join(map(str, cache["k"].shape))
+    assert cache["k"].shape == (32, B, max_seq, 8, 128)
+    writes = re.findall(r"%(\S+) = " + re.escape(slab) + r"\{[^}]*\} (\S+)\(",
+                        compiled.as_text())
+    assert writes, "no slab-shaped instruction found"
+    bad = [(name, op) for name, op in writes
+           if op in ("copy", "dynamic-update-slice")
+           or (op == "fusion" and ("copy" in name
+                                   or "dynamic-update-slice" in name))]
+    assert not bad, bad
