@@ -15,7 +15,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
-from harness import check, trace as tr, traffic as tf
+from harness import arch, check, trace as tr, traffic as tf
 from harness.loop import Driver, count_compiles
 from harness.record import Run
 from harness.spec import Bench, read_metric
@@ -29,20 +29,12 @@ TRACE_S = 5.0
 
 def program_config(cfg: dict):
     """The program's configuration of the served arch, checked against the
-    configuration file: a difference is an error, not a silent swap."""
+    fields that the configuration's architecture module says the file
+    implies: a difference is an error, not a silent swap."""
     from repro.configs import get_arch
 
     pc = get_arch(cfg["serving"]["arch"])
-    want = {"d_model": cfg["hidden_size"], "d_ff": cfg["intermediate_size"],
-            "num_heads": cfg["num_attention_heads"],
-            "num_kv_heads": cfg["num_key_value_heads"],
-            "head_dim": cfg["head_dim"], "num_layers":
-                cfg["num_hidden_layers"], "vocab_size": cfg["vocab_size"],
-            "mlp": cfg["hidden_act"], "rope_theta": cfg["rope_theta"],
-            "norm_eps": cfg["norm_eps"], "dtype": cfg["torch_dtype"],
-            "tie_embeddings": cfg["tie_word_embeddings"],
-            "family": "dense", "qkv_bias": False, "qk_norm": False,
-            "attn_window": None, "mrope": False}
+    want = arch.of(cfg).program_fields(cfg)
     got = {k: getattr(pc, k) for k in want}
     if got != want:
         bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}

@@ -1,101 +1,65 @@
 """Operations and bytes the algorithm needs, from the model's shapes alone.
 
-These count the work a step has to do, not what one implementation does: a
-decode step needs the served weights once and the keys and values of each
-live position, not a whole ``max_seq`` slab; causal attention needs the lower
-triangle of its scores.  The configuration is the JSON file of the cell
-(Hugging Face key names).
+The counts of a model are its architecture module's (``harness.arch``):
+each public count here sends the call to the module that the configuration
+names, so a metric reader reads any architecture through the same names.
+The configuration is the JSON file of the cell (Hugging Face key names).
+What stays here is what no architecture changes: a kernel's operations and
+bytes from its call shape, and the roofline time.
 """
 
 from __future__ import annotations
 
 from typing import Iterable, List, Tuple
 
-
-def dims(cfg: dict) -> dict:
-    return {"D": cfg["hidden_size"], "F": cfg["intermediate_size"],
-            "H": cfg["num_attention_heads"],
-            "K": cfg["num_key_value_heads"], "hd": cfg["head_dim"],
-            "L": cfg["num_hidden_layers"], "V": cfg["vocab_size"]}
-
-
-def layer_matmuls(cfg: dict) -> List[Tuple[int, int]]:
-    """(contraction, output) width of each weight matrix of one layer:
-    q, k, v, attention output, MLP in, MLP out (squared-ReLU MLP)."""
-    d = dims(cfg)
-    D, F, q, kv = d["D"], d["F"], d["H"] * d["hd"], d["K"] * d["hd"]
-    return [(D, q), (D, kv), (D, kv), (q, D), (D, F), (F, D)]
-
-
-def layer_matmul_params(cfg: dict) -> int:
-    return sum(a * b for a, b in layer_matmuls(cfg))
-
-
-def layer_params(cfg: dict) -> int:
-    """Matrices plus the two norm gains."""
-    return layer_matmul_params(cfg) + 2 * cfg["hidden_size"]
+from harness import arch
 
 
 def total_params(cfg: dict) -> int:
-    d = dims(cfg)
-    tables = d["V"] * d["D"] * (1 if cfg["tie_word_embeddings"] else 2)
-    return d["L"] * layer_params(cfg) + tables + d["D"]
+    return arch.of(cfg).total_params(cfg)
 
 
 def served_weight_bytes(cfg: dict, weights: str,
                         with_embedding: bool = False) -> int:
-    """Bytes of the served weights: bf16 everywhere, or (``int8``) the layer
-    matrices as int8 with one f32 scale per output channel."""
-    d = dims(cfg)
-    if weights == "bfloat16":
-        mats = 2 * layer_matmul_params(cfg)
-    elif weights == "int8":
-        mats = layer_matmul_params(cfg) + 4 * sum(
-            n for _, n in layer_matmuls(cfg))
-    else:
-        raise ValueError(weights)
-    out = d["L"] * (mats + 2 * 2 * d["D"]) + 2 * d["D"]   # + norms
-    out += 2 * d["D"] * d["V"]                            # output head
-    if with_embedding:
-        out += 2 * d["D"] * d["V"]
-    return out
-
-
-def kv_bytes_per_position(cfg: dict, kv_bytes: int = 2) -> int:
-    d = dims(cfg)
-    return 2 * d["L"] * d["K"] * d["hd"] * kv_bytes
+    """Bytes of the served weights in the format ``weights``; with
+    ``with_embedding``, the embedding table too."""
+    return arch.of(cfg).served_weight_bytes(cfg, weights, with_embedding)
 
 
 def decode_flops(cfg: dict, lengths: Iterable[int]) -> float:
     """One decode step over the live slots; ``lengths`` are the positions
-    each slot holds before the step (it attends over ``length + 1``)."""
-    d = dims(cfg)
-    per_token = 2 * (d["L"] * layer_matmul_params(cfg) + d["D"] * d["V"])
-    attn = 4 * d["L"] * d["H"] * d["hd"]
-    return float(sum(per_token + attn * (n + 1) for n in lengths))
+    each slot holds before the step."""
+    return arch.of(cfg).decode_flops(cfg, lengths)
 
 
 def decode_bytes(cfg: dict, lengths: Iterable[int], weights: str) -> float:
-    """Served weights except the embedding table, read once, plus the keys
-    and values of every live position."""
-    live = sum(n + 1 for n in lengths)
-    return float(served_weight_bytes(cfg, weights)
-                 + live * kv_bytes_per_position(cfg))
+    """Bytes one decode step over the live slots has to move: the served
+    weights it reads and the cached state of every live position."""
+    return arch.of(cfg).decode_bytes(cfg, lengths, weights)
 
 
 def prefill_flops(cfg: dict, S: int) -> float:
-    """A batch-1 prefill of ``S`` tokens: the layers on every token, causal
-    attention (the lower triangle), the output head on the last token."""
-    d = dims(cfg)
-    mats = 2 * d["L"] * layer_matmul_params(cfg) * S
-    attn = 4 * d["L"] * d["H"] * d["hd"] * S * (S + 1) / 2
-    return float(mats + attn + 2 * d["D"] * d["V"])
+    """A batch-1 prefill of ``S`` tokens."""
+    return arch.of(cfg).prefill_flops(cfg, S)
 
 
 def int8_calls(cfg: dict, M: int) -> List[Tuple[int, int, int]]:
     """(M, D, N) of every ``int8_matmul`` call one model pass makes on ``M``
-    rows: each layer matrix of each layer."""
-    return [(M, a, b) for a, b in layer_matmuls(cfg)] * cfg["num_hidden_layers"]
+    rows."""
+    return arch.of(cfg).int8_calls(cfg, M)
+
+
+# parts of the counts that a module may give besides (the dense decoders do)
+def layer_matmul_params(cfg: dict) -> int:
+    return arch.of(cfg).layer_matmul_params(cfg)
+
+
+def layer_params(cfg: dict) -> int:
+    return arch.of(cfg).layer_params(cfg)
+
+
+def kv_bytes_per_position(cfg: dict, kv_bytes: int = 2) -> int:
+    return arch.of(cfg).kv_bytes_per_position(cfg, kv_bytes)
 
 
 def int8_matmul_ops(M: int, D: int, N: int) -> float:

@@ -1,6 +1,9 @@
 """Find a cell's parts by name: ``BENCHMARK.json`` names the cells, and each
-configuration, traffic mix, metric reader and correctness limit is a file of
-its own under ``chipbench/``, so a later cell adds files and edits none."""
+configuration, traffic mix, metric reader, correctness limit and
+architecture module is a file of its own under ``chipbench/``, so a later
+cell, metric or model adds files and edits none.  A configuration file names
+its architecture (``"architecture"``), whose module ``harness.arch`` loads
+from the same checkout."""
 
 from __future__ import annotations
 
@@ -11,6 +14,9 @@ from types import ModuleType
 from typing import List, Optional
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
+# the key under which ``Bench.config`` notes the directory of the checkout's
+# architecture modules (``harness.arch``)
+ARCH_DIR_KEY = "_arch_dir"
 
 
 class Bench:
@@ -29,9 +35,13 @@ class Bench:
                        f"{[w['name'] for w in self.spec['workloads']]}")
 
     def config(self, name: str) -> dict:
+        """The configuration file's keys, and where its architecture module
+        is found: this checkout's ``chipbench/arch/``."""
         for c in self.spec["configs"]:
             if c["name"] == name:
-                return json.loads((self.root / c["file"]).read_text())
+                cfg = json.loads((self.root / c["file"]).read_text())
+                cfg[ARCH_DIR_KEY] = str(self.dir / "arch")
+                return cfg
         raise KeyError(f"no config {name!r} in BENCHMARK.json")
 
     def traffic(self, name: str) -> dict:
@@ -51,18 +61,25 @@ class Bench:
         return load_reader(self.dir / "metrics", metric)
 
 
-def load_reader(directory: pathlib.Path, metric: str) -> ModuleType:
-    """``<directory>/<metric>.py``: a module whose ``read(run)`` returns the
-    metric's value, or None where the run holds nothing to read."""
-    path = pathlib.Path(directory) / f"{metric}.py"
+def load_module(path: pathlib.Path, kind: str, name: str) -> ModuleType:
+    """The Python file ``path`` imported as a module of its own, named
+    ``chipbench_<kind>_<name>``; a missing file is named in the error."""
+    path = pathlib.Path(path)
     spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + metric.replace(".", "_").replace("-", "_"),
+        f"chipbench_{kind}_" + name.replace(".", "_").replace("-", "_"),
         path)
     if spec is None or not path.is_file():
-        raise FileNotFoundError(f"no reader for metric {metric!r} at {path}")
+        raise FileNotFoundError(f"no {kind} {name!r} at {path}")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reader(directory: pathlib.Path, metric: str) -> ModuleType:
+    """``<directory>/<metric>.py``: a module whose ``read(run)`` returns the
+    metric's value, or None where the run holds nothing to read."""
+    return load_module(pathlib.Path(directory) / f"{metric}.py", "metric",
+                       metric)
 
 
 def read_metric(mod: ModuleType, run) -> Optional[float]:

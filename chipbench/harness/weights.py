@@ -1,10 +1,13 @@
 """Random weights from the seed, made by the benchmark and not the program.
 
 The weights are the checkpoint the program is deployed from, in the tree its
-dense decoder loads (stacked layers), and the reference regenerates any one
-layer of them from the same seed on its own.  Every leaf draws from a key of
-its own, and each layer of a stacked leaf from a key of its own, so a layer
-made alone equals that layer of the whole tree, bit for bit.
+model loads, as the configuration's architecture module lists the leaves
+(``harness.arch``): each with its path, the layer count it is stacked over
+(none for a leaf made once), its shape and its init.  The reference
+regenerates any one layer of them from the same seed on its own.  Every leaf
+draws from a key of its own, and each layer of a stacked leaf from a key of
+its own, so a layer made alone equals that layer of the whole tree, bit for
+bit.
 """
 
 from __future__ import annotations
@@ -14,30 +17,17 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from harness.ops import dims
+from harness import arch
 
-# (path, stacked over layers, shape from dims, init): init is a standard
-# deviation for a matrix, or "gain" for a norm weight (1 + 0.1 N(0, 1), so
-# a norm that ignores its weight shows)
-LEAVES = (
-    (("embed",), False, lambda d: (d["V"], d["D"]), lambda d: 1.0),
-    (("final_norm",), False, lambda d: (d["D"],), "gain"),
-    (("lm_head",), False, lambda d: (d["D"], d["V"]), lambda d: d["D"] ** -0.5),
-    (("layers", "ln1"), True, lambda d: (d["D"],), "gain"),
-    (("layers", "ln2"), True, lambda d: (d["D"],), "gain"),
-    (("layers", "attn", "wq"), True, lambda d: (d["D"], d["H"] * d["hd"]),
-     lambda d: d["D"] ** -0.5),
-    (("layers", "attn", "wk"), True, lambda d: (d["D"], d["K"] * d["hd"]),
-     lambda d: d["D"] ** -0.5),
-    (("layers", "attn", "wv"), True, lambda d: (d["D"], d["K"] * d["hd"]),
-     lambda d: d["D"] ** -0.5),
-    (("layers", "attn", "wo"), True, lambda d: (d["H"] * d["hd"], d["D"]),
-     lambda d: (d["H"] * d["hd"]) ** -0.5),
-    (("layers", "mlp", "wi"), True, lambda d: (d["D"], d["F"]),
-     lambda d: d["D"] ** -0.5),
-    (("layers", "mlp", "wo"), True, lambda d: (d["F"], d["D"]),
-     lambda d: d["F"] ** -0.5),
-)
+
+def gain(z, d):
+    """A norm weight, 1 + 0.1 N(0, 1), so a norm that ignores it shows."""
+    return 1.0 + 0.1 * z
+
+
+def normal(std):
+    """N(0, std(d)^2), as a matrix or a table is drawn."""
+    return lambda z, d: z * std(d)
 
 
 def seed_key(seed: int):
@@ -57,8 +47,7 @@ def _leaf(key, idx, layer, shape, init, d, dtype):
     if layer is not None:
         k = jax.random.fold_in(k, layer)
     z = jax.random.normal(k, shape, jnp.float32)
-    x = 1.0 + 0.1 * z if init == "gain" else z * init(d)
-    return x.astype(dtype)
+    return init(z, d).astype(dtype)
 
 
 def _put(tree, path, value):
@@ -67,58 +56,67 @@ def _put(tree, path, value):
     tree[path[-1]] = value
 
 
-def _make(d, dtype, key):
+def _make(A, d, dtype, key):
     out = {}
-    for idx, (path, stacked, shape, init) in enumerate(LEAVES):
-        if stacked:
+    for idx, (path, stack, shape, init) in enumerate(A.LEAVES):
+        if stack is not None:
             value = jax.vmap(lambda l, shape=shape, init=init, idx=idx: _leaf(
                 key, idx, l, shape(d), init, d, dtype))(
-                    jnp.arange(d["L"], dtype=jnp.uint32))
+                    jnp.arange(d[stack], dtype=jnp.uint32))
         else:
             value = _leaf(key, idx, None, shape(d), init, d, dtype)
         _put(out, path, value)
     return out
 
 
-def _one_layer(d, dtype, key, layer):
+def _one_layer(A, d, dtype, stacks, key, at):
     out = {}
-    for idx, (path, stacked, shape, init) in enumerate(LEAVES):
-        if stacked:
-            _put(out, path[1:], _leaf(key, idx, layer, shape(d), init, d,
-                                      dtype))
+    for idx, (path, stack, shape, init) in enumerate(A.LEAVES):
+        if stack in stacks:
+            _put(out, path[1:], _leaf(key, idx, at[stacks.index(stack)],
+                                      shape(d), init, d, dtype))
     return out
 
 
-def _table(d, dtype, key, name):
-    for idx, (path, stacked, shape, init) in enumerate(LEAVES):
+def _table(A, d, dtype, key, name):
+    for idx, (path, stack, shape, init) in enumerate(A.LEAVES):
         if path == (name,):
             return _leaf(key, idx, None, shape(d), init, d, dtype)
     raise KeyError(name)
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted(kind: str, dkey: tuple, dtype: str, *static):
+def _jitted(kind: str, A, dkey: tuple, dtype: str, *static):
     d = dict(dkey)
     if kind == "all":
-        return jax.jit(functools.partial(_make, d, dtype))
+        return jax.jit(functools.partial(_make, A, d, dtype))
     if kind == "layer":
-        return jax.jit(functools.partial(_one_layer, d, dtype))
-    return jax.jit(lambda key: _table(d, dtype, key, static[0]))
+        return jax.jit(functools.partial(_one_layer, A, d, dtype, static))
+    return jax.jit(lambda key: _table(A, d, dtype, key, static[0]))
+
+
+def _arch(cfg: dict):
+    A = arch.of(cfg)
+    return A, tuple(sorted(A.dims(cfg).items()))
 
 
 def make_params(cfg: dict, seed: int, dtype: str):
     """The whole checkpoint, on the device, in one jitted call."""
-    return _jitted("all", tuple(sorted(dims(cfg).items())), dtype)(
-        seed_key(seed))
+    A, dkey = _arch(cfg)
+    return _jitted("all", A, dkey, dtype)(seed_key(seed))
 
 
 def layer_params(cfg: dict, seed: int, layer: int, dtype: str):
-    """Layer ``layer`` of :func:`make_params`'s stacked leaves."""
-    return _jitted("layer", tuple(sorted(dims(cfg).items())), dtype)(
-        seed_key(seed), jnp.uint32(layer))
+    """Layer ``layer`` of :func:`make_params`'s stacked leaves: its slice of
+    each stack it reads, each leaf under its path less the first key."""
+    A, dkey = _arch(cfg)
+    _, where = A.layer_at(dict(dkey), layer)
+    stacks = tuple(sorted(where))
+    return _jitted("layer", A, dkey, dtype, *stacks)(
+        seed_key(seed), tuple(jnp.uint32(where[s]) for s in stacks))
 
 
 def table(cfg: dict, seed: int, name: str, dtype: str):
-    """One unstacked leaf (``embed``, ``lm_head``, ``final_norm``)."""
-    return _jitted("table", tuple(sorted(dims(cfg).items())), dtype, name)(
-        seed_key(seed))
+    """One leaf made once (an embedding, an output table, a final norm)."""
+    A, dkey = _arch(cfg)
+    return _jitted("table", A, dkey, dtype, name)(seed_key(seed))

@@ -1,5 +1,6 @@
-"""A benchmark checkout in miniature for the CPU tests: the real readers and
-``BENCHMARK.json`` layout, a two-layer minitron cut and a small chat mix."""
+"""A benchmark checkout in miniature for the CPU tests: the real readers,
+architecture modules and ``BENCHMARK.json`` layout, a two-layer minitron cut
+and a small chat mix."""
 
 from __future__ import annotations
 
@@ -15,7 +16,8 @@ for p in (str(CB), str(ROOT / "src")):
         sys.path.insert(0, p)
 
 TINY = {
-    "name": "tiny", "model_type": "nemotron", "hidden_size": 256,
+    "name": "tiny", "model_type": "nemotron", "architecture": "dense_relu2",
+    "hidden_size": 256,
     "intermediate_size": 512, "num_attention_heads": 4,
     "num_key_value_heads": 1, "head_dim": 32, "num_hidden_layers": 2,
     "vocab_size": 1024, "hidden_act": "relu2", "norm_eps": 1e-05,
@@ -47,7 +49,9 @@ def make_checkout(tmp: pathlib.Path, limits=None, config=TINY,
     cb = tmp / "chipbench"
     for d in ("configs", "traffic", "limits"):
         (cb / d).mkdir(parents=True, exist_ok=True)
-    shutil.copytree(CB / "metrics", cb / "metrics", dirs_exist_ok=True)
+    for d in ("metrics", "arch"):
+        shutil.copytree(CB / d, cb / d, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     real = json.loads((ROOT / "BENCHMARK.json").read_text())
     (cb / "configs" / "tiny.json").write_text(json.dumps(config))
     (cb / "traffic" / "chat.json").write_text(json.dumps(dict(CHAT, **chat)))
