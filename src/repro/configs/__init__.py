@@ -21,6 +21,7 @@ from repro.configs.qwen3_8b import CONFIG as _qwen3
 from repro.configs.qwen2_vl_2b import CONFIG as _qwen2vl
 from repro.configs.yi_9b import CONFIG as _yi
 from repro.configs.whisper_small import CONFIG as _whisper
+from repro.configs.granite_4_0_h_micro import CONFIG as _granite_micro
 
 ARCHS = {
     c.name: c
@@ -35,6 +36,7 @@ ARCHS = {
         _qwen2vl,
         _yi,
         _whisper,
+        _granite_micro,
     )
 }
 
@@ -61,7 +63,7 @@ def applicable(arch: ModelConfig, shape: ShapeConfig) -> bool:
         # sub-quadratic required: SSM/hybrid native; attention archs need a window
         return (
             arch.attention_free
-            or arch.family == "hybrid"
+            or arch.family in ("hybrid", "interleaved")
             or arch.attn_window is not None
             or arch.long_context_window is not None
         )

@@ -18,7 +18,8 @@ class ModelConfig:
     """Architecture description (pure data; consumed by models/transformer.py)."""
 
     name: str
-    family: str                      # dense | moe | ssm | hybrid | vlm | audio
+    family: str                      # dense | moe | ssm | hybrid | interleaved
+                                     # | vlm | audio
     num_layers: int
     d_model: int
     num_heads: int
@@ -33,7 +34,8 @@ class ModelConfig:
     attn_window: Optional[int] = None          # native sliding window (mixtral)
     long_context_window: Optional[int] = None  # SWA used only for long_500k on
                                                # otherwise-full-attention archs
-    rope_theta: float = 1e6
+    rope_theta: float = 1e6          # 0: no rotary positions (whisper, NoPE)
+    attn_scale: Optional[float] = None  # None -> head_dim ** -0.5
     mrope: bool = False              # qwen2-vl multimodal rope (t/h/w sections)
     mrope_sections: tuple = (16, 24, 24)  # head_dim/2 split
 
@@ -51,6 +53,11 @@ class ModelConfig:
     ssm_head_dim: int = 64           # rwkv6/mamba2 per-head channel dim
     attn_every: int = 0              # zamba2: shared attn block every N layers
     ssm_expand: int = 2              # mamba2 d_inner = expand * d_model
+    ssm_chunk: int = 256             # mamba2 prefill: SSD chunk length
+    # interleaved: the kind of each layer of one period ("mamba" |
+    # "attention"), repeated num_layers / len(layer_pattern) times; every
+    # layer has its own weights and an MLP
+    layer_pattern: tuple = ()
 
     # --- enc-dec (whisper) ---------------------------------------------------
     encoder_layers: int = 0
@@ -63,6 +70,12 @@ class ModelConfig:
     unroll_layers: bool = False      # python-loop layers (accurate HLO cost
                                      # accounting; scan hides trip counts)
     tie_embeddings: bool = False
+    # muP multipliers (granite): embeddings times ``embedding_multiplier``,
+    # each residual branch times ``residual_multiplier``, logits divided by
+    # ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
     source: str = ""                 # citation bracket from the assignment
@@ -71,6 +84,8 @@ class ModelConfig:
         if self.head_dim == 0 and self.num_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         assert self.num_heads % max(self.num_kv_heads, 1) == 0, self.name
+        if self.family == "interleaved":
+            assert self.num_layers % len(self.layer_pattern) == 0, self.name
 
     @property
     def q_per_kv(self) -> int:
@@ -92,6 +107,11 @@ class ModelConfig:
     def d_inner(self) -> int:
         """Mamba2 inner width."""
         return self.ssm_expand * self.d_model
+
+    def layers_of(self, kind: str) -> int:
+        """interleaved: how many layers of ``kind`` the whole depth holds."""
+        periods = self.num_layers // len(self.layer_pattern)
+        return periods * self.layer_pattern.count(kind)
 
     # -- parameter count (for 6ND model-flops accounting) ---------------------
     def param_count(self, active_only: bool = False) -> int:
@@ -122,6 +142,15 @@ class ModelConfig:
             return n + L * (mamba + 2 * D * F // 2) + n_attn_blocks * attn
 
         attn = D * (H + 2 * K) * hd + H * hd * D
+        if self.family == "interleaved":
+            di, S = self.d_inner, self.ssm_state
+            heads = di // self.ssm_head_dim
+            conv = di + 2 * S
+            mamba = (D * (2 * di + 2 * S + heads) + 5 * conv + 3 * heads
+                     + di + di * D)   # in_proj, conv w+b, A/D/dt, gnorm, out
+            return (n + D + self.num_layers * (3 * D * F + 2 * D)
+                    + self.layers_of("mamba") * mamba
+                    + self.layers_of("attention") * attn)
         if self.mlp == "swiglu":
             mlp_dense = 3 * D * F
         else:
@@ -198,6 +227,11 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     if cfg.family == "ssm":
         changes["num_heads"] = 4
         changes["num_kv_heads"] = 4
+    if cfg.family == "interleaved":
+        # one layer of each kind; chunks short enough that a 16-token
+        # prompt spans several
+        changes["layer_pattern"] = ("mamba", "attention")
+        changes["ssm_chunk"] = 8
     if cfg.mrope:
         # rescale the t/h/w frequency sections to the reduced head_dim
         full = sum(cfg.mrope_sections)

@@ -71,6 +71,10 @@ class Engine:
         self.cfg = cfg
         self.params = params
         self.max_seq = max_seq
+        # what one slot of the decode cache holds: keys and values per
+        # position, and recurrent state (zero for attention-only models)
+        self.kv_bytes_per_token, self.state_bytes_per_slot = \
+            transformer.cache_bytes(cfg, max_seq)
 
     # -- execution hooks ------------------------------------------------------
     def _prefill(self, tokens):

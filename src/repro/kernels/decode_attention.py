@@ -63,9 +63,10 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def decode_attention(
     q, k_cache, v_cache, lengths, *, window=None, block_s: int = 512,
-    interpret: bool = False,
+    interpret: bool = False, scale=None,
 ):
-    """q: (B, K, G, dh); caches: (B, K, S, dh); lengths: (B,) incl. current.
+    """q: (B, K, G, dh); caches: (B, K, S, dh); lengths: (B,) incl. current;
+    ``scale`` the scores' factor (None: dh ** -0.5).
 
     Returns (B, K, G, dh).
     """
@@ -74,7 +75,8 @@ def decode_attention(
     block_s = min(block_s, S)
     ns = pl.cdiv(S, block_s)
     kernel = functools.partial(
-        _kernel, scale=dh ** -0.5, window=window, block_s=block_s
+        _kernel, scale=dh ** -0.5 if scale is None else scale,
+        window=window, block_s=block_s
     )
     return pl.pallas_call(
         kernel,

@@ -39,12 +39,13 @@ def flash_attention(q, k, v, *, causal=True, window=None, block_q=128,
                   block_kv=block_kv, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "block_s", "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "block_s", "interpret",
+                                             "scale"))
 def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
-                     block_s=512, interpret=None):
+                     block_s=512, interpret=None, scale=None):
     interpret = _default_interpret() if interpret is None else interpret
     return _decode(q, k_cache, v_cache, lengths, window=window,
-                   block_s=block_s, interpret=interpret)
+                   block_s=block_s, interpret=interpret, scale=scale)
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "block_f", "block_d",

@@ -41,6 +41,7 @@ def attention(
     q_offset=0,
     kv_lengths=None,
     block_kv: int = 512,
+    scale: Optional[float] = None,
 ):
     """Chunked flash attention with a FLASH BACKWARD (custom VJP).
 
@@ -53,6 +54,7 @@ def attention(
     q_offset: position of q[0] within the kv timeline (int or (B,) array).
     kv_lengths: optional (B,) valid kv lengths (positions >= length masked).
     window: sliding window width (attend to kv in (q_pos-window, q_pos]).
+    scale: the scores' factor; None is dh ** -0.5.
     """
     q_off = jnp.asarray(q_offset)
     has_kv_len = kv_lengths is not None
@@ -62,7 +64,7 @@ def attention(
         else jnp.zeros((q.shape[0],), jnp.int32)  # unused when has_kv_len=False
     )
     return _attention_vjp(q, k, v, q_off, kv_len, causal, window, block_kv,
-                          has_kv_len)
+                          has_kv_len, scale)
 
 
 def _mask_for(q_pos, k_pos, kv_len, nk, causal, window, has_kv_len=True):
@@ -84,22 +86,23 @@ def _mask_for(q_pos, k_pos, kv_len, nk, causal, window, has_kv_len=True):
 import functools as _functools  # noqa: E402
 
 
-@_functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _attention_vjp(q, k, v, q_offset, kv_lengths, causal, window, block_kv,
-                   has_kv_len):
+                   has_kv_len, scale):
     out, _ = _attention_fwd_core(q, k, v, q_offset, kv_lengths, causal,
-                                 window, block_kv, has_kv_len)
+                                 window, block_kv, has_kv_len, scale)
     return out
 
 
 def _attention_fwd_rule(q, k, v, q_offset, kv_lengths, causal, window,
-                        block_kv, has_kv_len):
+                        block_kv, has_kv_len, scale):
     out, lse = _attention_fwd_core(q, k, v, q_offset, kv_lengths, causal,
-                                   window, block_kv, has_kv_len)
+                                   window, block_kv, has_kv_len, scale)
     return out, (q, k, v, out, lse, q_offset, kv_lengths)
 
 
-def _attention_bwd_rule(causal, window, block_kv, has_kv_len, res, dout):
+def _attention_bwd_rule(causal, window, block_kv, has_kv_len, scale, res,
+                        dout):
     q, k, v, out, lse, q_offset, kv_lengths = res
     # residuals may deliver q_offset as a plain Python int (weak-typed scalar
     # concretized by the VJP machinery); normalize so .ndim/.astype work
@@ -107,7 +110,7 @@ def _attention_bwd_rule(causal, window, block_kv, has_kv_len, res, dout):
     B, Sq, H, dh = q.shape
     _, T, K, _ = k.shape
     G = H // K
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     qf = q.astype(jnp.float32).reshape(B, Sq, K, G, dh) * scale
     do = dout.astype(jnp.float32).reshape(B, Sq, K, G, dh)
     of = out.astype(jnp.float32).reshape(B, Sq, K, G, dh)
@@ -165,14 +168,14 @@ _attention_vjp.defvjp(_attention_fwd_rule, _attention_bwd_rule)
 
 
 def _attention_fwd_core(q, k, v, q_offset, kv_lengths, causal, window,
-                        block_kv, has_kv_len=True):
+                        block_kv, has_kv_len=True, scale=None):
     """Returns (out, lse) via the chunked online-softmax forward."""
     q_offset = jnp.asarray(q_offset)
     B, Sq, H, dh = q.shape
     _, T, K, _ = k.shape
     G = H // K
     out_dtype = q.dtype
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
 
     qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, K, G, dh)
     k, nk = _pad_to(k, block_kv, axis=1)
@@ -225,12 +228,13 @@ def _attention_fwd_core(q, k, v, q_offset, kv_lengths, causal, window,
 
 
 def attention_reference(q, k, v, *, causal=True, window=None, q_offset=0,
-                        kv_lengths=None):
+                        kv_lengths=None, scale=None):
     """O(S^2)-memory oracle for tests."""
     B, Sq, H, dh = q.shape
     _, T, K, _ = k.shape
     G = H // K
-    qf = q.astype(jnp.float32).reshape(B, Sq, K, G, dh) * dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
+    qf = q.astype(jnp.float32).reshape(B, Sq, K, G, dh) * scale
     s = jnp.einsum("bqkgd,btkd->bqkgt", qf, k.astype(jnp.float32))
     q_pos = jnp.arange(Sq)[None, :] + (
         q_offset if isinstance(q_offset, (int, float)) else q_offset[:, None]
@@ -259,6 +263,7 @@ def decode_attention(
     k_new=None,
     v_new=None,
     block_kv: int = 1024,  # kept for API compat; direct path ignores it
+    scale: Optional[float] = None,
 ):
     """Single-token attention over a KV cache.
 
@@ -267,7 +272,7 @@ def decode_attention(
     ``lengths``; ``window`` keeps the cache entries in (lengths - window,
     lengths).  ``k_new``/``v_new`` ((B, K, dh), in the cache dtype) are the
     query token's own kv: their score and value join the cache's in one
-    softmax.
+    softmax.  ``scale`` is the scores' factor; None is dh ** -0.5.
 
     Uses the DIRECT (non-chunked) softmax: the (B, K, G, S) score tensor for
     one query token is small, and the un-chunked einsum lets GSPMD implement
@@ -279,7 +284,7 @@ def decode_attention(
     S = k_cache.shape[1]
     K = k_cache.shape[2]
     G = H // K
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     qf = (q.astype(jnp.float32) * scale).astype(k_cache.dtype)
     qf = qf.reshape(B, K, G, dh)
     s = jnp.einsum(

@@ -154,31 +154,42 @@ def rwkv6_block(p, x, head_dim: int, cache=None):
 
 
 # =============================================================================
-# Mamba2 (SSD, scalar-identity A per head), used by zamba2
+# Mamba2 (SSD, scalar-identity A per head, one group of B/C): zamba2's
+# layers and granite's
 # =============================================================================
 
 
-def init_mamba2_layer(key, d_model: int, d_inner: int, ssm_state: int,
-                      head_dim: int, dtype):
+def init_mamba2_mixer(key, d_model: int, d_inner: int, ssm_state: int,
+                      head_dim: int, dtype, vec_dtype=jnp.float32):
+    """The mixer's weights; ``vec_dtype`` stores the per-head A_log, D and
+    dt_bias, which the mixer computes with in f32 whatever they are stored
+    in."""
     nh = d_inner // head_dim
     S = ssm_state
     ks = jax.random.split(key, 3)
     proj_out = 2 * d_inner + 2 * S + nh
     return {
-        "norm_w": jnp.ones((d_model,), dtype),
         "in_proj": (
             jax.random.normal(ks[0], (d_model, proj_out)) * d_model ** -0.5
         ).astype(dtype),
         "conv_w": (jax.random.normal(ks[1], (4, d_inner + 2 * S)) * 0.2).astype(dtype),
         "conv_b": jnp.zeros((d_inner + 2 * S,), dtype),
-        "A_log": jnp.zeros((nh,), jnp.float32),
-        "D_skip": jnp.ones((nh,), jnp.float32),
-        "dt_bias": jnp.zeros((nh,), jnp.float32),
+        "A_log": jnp.zeros((nh,), vec_dtype),
+        "D_skip": jnp.ones((nh,), vec_dtype),
+        "dt_bias": jnp.zeros((nh,), vec_dtype),
         "gnorm_w": jnp.ones((d_inner,), dtype),
         "out_proj": (
             jax.random.normal(ks[2], (d_inner, d_model)) * d_inner ** -0.5
         ).astype(dtype),
     }
+
+
+def init_mamba2_layer(key, d_model: int, d_inner: int, ssm_state: int,
+                      head_dim: int, dtype):
+    """The mixer with its own pre-norm (zamba2's Mamba layers)."""
+    return {"norm_w": jnp.ones((d_model,), dtype),
+            **init_mamba2_mixer(key, d_model, d_inner, ssm_state, head_dim,
+                                dtype)}
 
 
 def _causal_depthwise_conv(x, w, b, conv_state=None):
@@ -195,8 +206,76 @@ def _causal_depthwise_conv(x, w, b, conv_state=None):
     return jax.nn.silu(y), xp[:, -(W - 1):]
 
 
-def mamba2_mix(p, x, *, head_dim: int, ssm_state: int, cache=None):
-    """x: (B,T,D). Returns (y, new_cache)."""
+_HI = jax.lax.Precision.HIGHEST
+
+
+def ssd_step(h, x_t, B_t, C_t, dt_t, A):
+    """One step of the recurrence: h (B,nh,hd,S) f32; x_t (B,nh,hd); B_t,
+    C_t (B,S); dt_t (B,nh).  Returns (h', y_t (B,nh,hd))."""
+    decay = jnp.exp(A * dt_t)
+    h = decay[..., None, None] * h + (dt_t[..., None] * x_t)[..., None] \
+        * B_t[:, None, None, :]
+    return h, jnp.einsum("bnds,bs->bnd", h, C_t, precision=_HI)
+
+
+def ssd_scan(x, dt, A, Bs, Cs, h0):
+    """The recurrence token by token: x (B,T,nh,hd), dt (B,T,nh), Bs/Cs
+    (B,T,S), h0 (B,nh,hd,S), all f32.  Returns (y (B,T,nh,hd), h_T)."""
+    def body(h, inp):
+        return ssd_step(h, *inp, A)
+
+    seq = tuple(a.swapaxes(0, 1) for a in (x, Bs, Cs, dt))
+    h, ys = jax.lax.scan(body, h0, seq)
+    return ys.swapaxes(0, 1), h
+
+
+def ssd_chunked(x, dt, A, Bs, Cs, h0, chunk: int):
+    """The same recurrence in chunks (Mamba2's SSD form): within a chunk,
+    matrix products over its positions; between chunks, the state carried.
+    Shapes as :func:`ssd_scan`.  A ragged tail is padded with dt = 0, which
+    leaves the state unchanged."""
+    B, T, nh, hd = x.shape
+    l = min(chunk, T)
+    pad = (-T) % l
+    if pad:
+        x, dt, Bs, Cs = (jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+                         for a in (x, dt, Bs, Cs))
+    c = (T + pad) // l
+    x = x.reshape(B, c, l, nh, hd)
+    dt = dt.reshape(B, c, l, nh)
+    Bs = Bs.reshape(B, c, l, -1)
+    Cs = Cs.reshape(B, c, l, -1)
+    a = jnp.cumsum(A * dt, axis=2)                      # (B,c,l,nh), <= 0
+    a_h = jnp.moveaxis(a, -1, 2)                        # (B,c,nh,l)
+    # decay from position j to i inside a chunk, i >= j
+    seg = a_h[..., :, None] - a_h[..., None, :]         # (B,c,nh,l,l)
+    causal = jnp.tril(jnp.ones((l, l), bool))
+    decay = jnp.where(causal, jnp.exp(jnp.where(causal, seg, 0.0)), 0.0)
+    xdt = x * dt[..., None]                             # (B,c,l,nh,hd)
+    cb = jnp.einsum("bcis,bcjs->bcij", Cs, Bs, precision=_HI)
+    y = jnp.einsum("bcij,bchij,bcjhd->bcihd", cb, decay, xdt, precision=_HI)
+    # each chunk's own contribution to the state at its end
+    to_end = jnp.exp(a[:, :, -1:] - a)                  # (B,c,l,nh)
+    states = jnp.einsum("bcjs,bcjh,bcjhd->bchds", Bs, to_end, xdt,
+                        precision=_HI)                  # (B,c,nh,hd,S)
+    whole = jnp.exp(a[:, :, -1])                        # (B,c,nh)
+
+    def carry(h, inp):
+        st, w = inp
+        return w[..., None, None] * h + st, h
+
+    h, h_in = jax.lax.scan(carry, h0, (states.swapaxes(0, 1),
+                                       whole.swapaxes(0, 1)))
+    h_in = h_in.swapaxes(0, 1)                          # state entering each
+    y = y + jnp.einsum("bcis,bchds,bcih->bcihd", Cs, h_in, jnp.exp(a),
+                       precision=_HI)
+    return y.reshape(B, c * l, nh, hd)[:, :T], h
+
+
+def mamba2_mix(p, x, *, head_dim: int, ssm_state: int, cache=None,
+               chunk: int = 256):
+    """x: (B,T,D). Returns (y, new_cache).  A single token (decode) takes
+    one step of the recurrence; a sequence runs in chunks of ``chunk``."""
     B, T, D = x.shape
     c = cache or {}
     zxbcdt = x @ p["in_proj"]
@@ -208,35 +287,24 @@ def mamba2_mix(p, x, *, head_dim: int, ssm_state: int, cache=None):
         xBC, p["conv_w"], p["conv_b"], c.get("conv")
     )
     xs, Bs, Cs = jnp.split(xBC, [d_inner, d_inner + S], axis=-1)
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])        # (B,T,nh)
-    A = -jnp.exp(p["A_log"])                                           # (nh,)
-    decay = jnp.exp(A * dt)                                            # (B,T,nh)
-    xh = xs.astype(jnp.float32).reshape(B, T, nh, head_dim)
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    dt = jax.nn.softplus(f32(dt) + f32(p["dt_bias"]))                  # (B,T,nh)
+    A = -jnp.exp(f32(p["A_log"]))                                      # (nh,)
+    xh = f32(xs).reshape(B, T, nh, head_dim)
+    Bs, Cs = f32(Bs), f32(Cs)
     h0 = (
-        c["ssm"].astype(jnp.float32)
+        f32(c["ssm"])
         if "ssm" in c
         else jnp.zeros((B, nh, head_dim, S), jnp.float32)
     )
-
-    def body(h, inp):
-        x_t, B_t, C_t, dec_t, dt_t = inp  # (B,nh,hd),(B,S),(B,S),(B,nh),(B,nh)
-        h = dec_t[..., None, None] * h + (dt_t[..., None] * x_t)[
-            ..., None
-        ] * B_t[:, None, None, :]
-        y = jnp.einsum("bnds,bs->bnd", h, C_t)
-        return h, y
-
-    seq = (
-        xh.swapaxes(0, 1),
-        Bs.astype(jnp.float32).swapaxes(0, 1),
-        Cs.astype(jnp.float32).swapaxes(0, 1),
-        decay.swapaxes(0, 1),
-        dt.swapaxes(0, 1),
-    )
-    h_final, ys = jax.lax.scan(body, h0, seq)
-    y = ys.swapaxes(0, 1) + p["D_skip"][:, None] * xh                  # (B,T,nh,hd)
+    if T == 1:
+        h_final, y = ssd_step(h0, xh[:, 0], Bs[:, 0], Cs[:, 0], dt[:, 0], A)
+        y = y[:, None]
+    else:
+        y, h_final = ssd_chunked(xh, dt, A, Bs, Cs, h0, chunk)
+    y = y + f32(p["D_skip"])[:, None] * xh                             # (B,T,nh,hd)
     y = y.reshape(B, T, d_inner)
-    y = layers.rms_norm(y * jax.nn.silu(z.astype(jnp.float32)), p["gnorm_w"])
+    y = layers.rms_norm(y * jax.nn.silu(f32(z)), p["gnorm_w"])
     y = y.astype(x.dtype) @ p["out_proj"]
     return y, {"conv": conv_state, "ssm": h_final}
 

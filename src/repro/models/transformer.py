@@ -8,6 +8,10 @@ Families:
   hybrid (zamba2)   : nested scan — groups of ``attn_every`` Mamba2 layers,
                       each group followed by a SHARED (weight-tied) attention
                       block with a per-group norm gain.
+  interleaved       : Mamba2 and attention layers in a repeating pattern
+  (granite-4.0-h)     (``cfg.layer_pattern``), each layer with its own
+                      weights and an MLP; scan over periods.  The cache
+                      holds both kinds of state side by side.
   audio (whisper)   : enc-dec; conv/mel frontend stubbed (embeddings in).
 
 Three entry points, used by training, serving and the dry-run:
@@ -67,6 +71,15 @@ def _init_decoder_layer(key, cfg: ModelConfig, dtype):
     return p
 
 
+def _init_mlp_layer(key, cfg: ModelConfig, dtype):
+    """The part of an interleaved layer every kind has: both norms, the MLP."""
+    return {
+        "ln1": jnp.ones((cfg.d_model,), dtype),
+        "ln2": jnp.ones((cfg.d_model,), dtype),
+        "mlp": layers.init_mlp(key, cfg.d_model, cfg.d_ff, cfg.mlp, dtype),
+    }
+
+
 def _init_xattn_layer(key, cfg: ModelConfig, dtype):
     """Whisper decoder layer: self-attn + cross-attn + gelu mlp."""
     k1, k2, k3 = jax.random.split(key, 3)
@@ -110,6 +123,16 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
         )
         params["shared"] = _init_decoder_layer(kh, cfg, dtype)
         params["group_gain"] = jnp.ones((G, D), dtype)
+    elif cfg.family == "interleaved":
+        nm, na = cfg.layers_of("mamba"), cfg.layers_of("attention")
+        params["layers"] = _stacked(_init_mlp_layer, kl, cfg.num_layers,
+                                    cfg, dtype)
+        params["mamba_layers"] = {"mamba": _stacked(
+            ssm.init_mamba2_mixer, kh, nm, cfg.d_model, cfg.d_inner,
+            cfg.ssm_state, cfg.ssm_head_dim, dtype,
+            dtype)}  # granite's checkpoint keeps A_log, D, dt_bias in it
+        params["attn_layers"] = {"attn": _stacked(_init_attn, ko, na, cfg,
+                                                  dtype)}
     elif cfg.family == "audio":
         params["enc_layers"] = _stacked(
             _init_decoder_layer, kl, cfg.encoder_layers, cfg, dtype
@@ -174,7 +197,7 @@ def _self_attention_full(p, cfg, x, angles, *, causal=True, window=None):
     """Full-sequence self attention. Returns (out, (k, v))."""
     B, S, _ = x.shape
     q, k, v = _qkv(p, cfg, x, angles)
-    o = attention(q, k, v, causal=causal, window=window)
+    o = attention(q, k, v, causal=causal, window=window, scale=cfg.attn_scale)
     return layers.dense(o.reshape(B, S, -1), p["wo"]), (k, v)
 
 
@@ -203,32 +226,48 @@ def _self_attention_decode(p, cfg, x, angles, kc, vc, lengths, *, window=None):
         kw = jnp.take_along_axis(kc, idx[:, :, None, None], axis=1)
         vw = jnp.take_along_axis(vc, idx[:, :, None, None], axis=1)
         o = decode_attention(q[:, 0], kw, vw, lengths - start,
-                             k_new=k_new, v_new=v_new)
+                             k_new=k_new, v_new=v_new, scale=cfg.attn_scale)
     else:
         o = decode_attention(q[:, 0], kc, vc, lengths, window=window,
-                             k_new=k_new, v_new=v_new)
+                             k_new=k_new, v_new=v_new, scale=cfg.attn_scale)
     return layers.dense(o.reshape(B, 1, -1), p["wo"]), k_new, v_new
 
 
 def _append_kv(cache, k_new, v_new, lengths, uniform: bool):
     """Write every layer's new k/v, (L, B, K, hd), into the (L, B, S, K, hd)
-    slabs at each slot's ``lengths``: one in-place update of the donated
-    cache after the layer loop.  Returns the cache with lengths += 1.
+    slabs (or (L, B, S, K * hd)) at each slot's ``lengths``: one in-place
+    update of the donated cache after the layer loop.  Returns the cache
+    with lengths += 1.
 
     uniform=True (every slot at one position) writes with a scalar
     dynamic-update-slice, which GSPMD partitions on a sharded sequence dim
     without the f32 set->add scatter rewrite (2x write traffic).
     """
+    tail = cache["k"].shape[3:]
+    flat = k_new.shape[2:] != tail      # a slab of (..., K * hd)
+    if flat:
+        k_new = k_new.reshape(*k_new.shape[:2], *tail)
+        v_new = v_new.reshape(*v_new.shape[:2], *tail)
     if uniform:
-        at = (0, 0, lengths[0], 0, 0)
+        at = (0, 0, lengths[0]) + (0,) * len(tail)
         k = jax.lax.dynamic_update_slice(cache["k"], k_new[:, :, None], at)
         v = jax.lax.dynamic_update_slice(cache["v"], v_new[:, :, None], at)
     else:
         bidx = jnp.arange(lengths.shape[0])
-        k = cache["k"].at[:, bidx, lengths].set(
-            k_new, unique_indices=True, mode="promise_in_bounds")
-        v = cache["v"].at[:, bidx, lengths].set(
-            v_new, unique_indices=True, mode="promise_in_bounds")
+        if flat:
+            # a layer at a time: one scatter over every layer of a flat
+            # slab copies the slab twice on the TPU
+            k, v = cache["k"], cache["v"]
+            for l in range(k.shape[0]):
+                k = k.at[l, bidx, lengths].set(
+                    k_new[l], unique_indices=True, mode="promise_in_bounds")
+                v = v.at[l, bidx, lengths].set(
+                    v_new[l], unique_indices=True, mode="promise_in_bounds")
+        else:
+            k = cache["k"].at[:, bidx, lengths].set(
+                k_new, unique_indices=True, mode="promise_in_bounds")
+            v = cache["v"].at[:, bidx, lengths].set(
+                v_new, unique_indices=True, mode="promise_in_bounds")
     return dict(cache, k=k, v=v, lengths=lengths + 1)
 
 
@@ -279,7 +318,7 @@ def _decoder_layer(p, cfg, x, angles, *, window, collect_kv, remat=False):
 
 
 def _rope_angles_for(cfg: ModelConfig, batch, B, S):
-    if cfg.rope_theta == 0.0:  # whisper: sinusoidal abs positions, no rope
+    if cfg.rope_theta == 0.0:  # whisper (sinusoidal positions), NoPE: no rope
         return None
     if cfg.mrope:
         pos = batch.get("positions")
@@ -306,6 +345,8 @@ def _embed_in(params, cfg, batch):
         x = batch["embeds"].astype(cfg.jnp_dtype)
     else:
         x = layers.embed(batch["tokens"], params["embed"])
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     # pin batch sharding on the residual stream entry (the embedding table's
     # own sharding must not leak onto activations)
     return ctx.constrain(x, ("dp", None, None))
@@ -316,7 +357,10 @@ def _lm_logits(params, cfg, x, logits_for: str = "all"):
         x = x[:, -1:]
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     table = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return layers.unembed(x, table)
+    logits = layers.unembed(x, table)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def forward(params, cfg: ModelConfig, batch, *, remat: bool = False,
@@ -370,6 +414,11 @@ def forward(params, cfg: ModelConfig, batch, *, remat: bool = False,
         return _forward_hybrid(params, cfg, batch, x, collect_kv=collect_kv,
                                remat=remat, logits_for=logits_for)
 
+    if cfg.family == "interleaved":
+        return _forward_interleaved(params, cfg, batch, x,
+                                    collect_kv=collect_kv, remat=remat,
+                                    logits_for=logits_for)
+
     raise ValueError(cfg.family)
 
 
@@ -413,6 +462,123 @@ def _forward_hybrid(params, cfg, batch, x, *, collect_kv, remat=False,
         out["state"] = mcaches  # leaves: (G, ae, B, ...)
         out["kv"] = kvs         # (G, B, S, K, hd) pair
     return out
+
+
+# -- interleaved (granite-4.0-h): Mamba2 and attention layers in a pattern --
+
+
+def _at(tree, i):
+    """Layer ``i`` of a stacked tree, by a dynamic index."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
+
+
+def _residual(cfg: ModelConfig, x, h):
+    if cfg.residual_multiplier != 1.0:
+        h = h * cfg.residual_multiplier
+    return x + h
+
+
+def _period(cfg: ModelConfig, params, n, x, mixer):
+    """Period ``n`` (a traced index) of the layers on x.  Every layer reads
+    its weights from its stack at its index, so no stack is regrouped and
+    each keeps the layout the device gave it.  ``mixer(kind, at, w, xn)`` is
+    the sequence mixer of the layer of ``kind`` at index ``at`` of that
+    kind's stack, given its weights and the normed input; it returns its
+    output and what it keeps, per kind in layer order."""
+    P = len(cfg.layer_pattern)
+    stacks = {"mamba": params["mamba_layers"]["mamba"],
+              "attention": params["attn_layers"]["attn"]}
+    kept = {"mamba": [], "attention": []}
+    for j, kind in enumerate(cfg.layer_pattern):
+        lp = _at(params["layers"], n * P + j)
+        at = n * cfg.layer_pattern.count(kind) + len(kept[kind])
+        h, keep = mixer(kind, at, _at(stacks[kind], at),
+                        layers.rms_norm(x, lp["ln1"], cfg.norm_eps))
+        kept[kind].append(keep)
+        x = _residual(cfg, x, h)
+        h, _ = _ffn(lp, cfg, layers.rms_norm(x, lp["ln2"], cfg.norm_eps))
+        x = _residual(cfg, x, h)
+    return x, {k: jax.tree.map(lambda *a: jnp.stack(a), *v)
+               for k, v in kept.items() if v and v[0] is not None}
+
+
+def _periods(cfg: ModelConfig):
+    return jnp.arange(cfg.num_layers // len(cfg.layer_pattern),
+                      dtype=jnp.int32)
+
+
+def _flat(a):
+    """(periods, per period, ...) -> (layers, ...)."""
+    return a.reshape(-1, *a.shape[2:])
+
+
+def _forward_interleaved(params, cfg, batch, x, *, collect_kv, remat=False,
+                         logits_for: str = "all"):
+    angles = _rope_angles_for(cfg, batch, *x.shape[:2])
+
+    def mixer(kind, at, w, xn):
+        if kind == "mamba":
+            return ssm.mamba2_mix(w, xn, head_dim=cfg.ssm_head_dim,
+                                  ssm_state=cfg.ssm_state,
+                                  chunk=cfg.ssm_chunk)
+        return _self_attention_full(w, cfg, xn, angles)
+
+    def body(x, n):
+        x = ctx.constrain(x, ("dp", None, None))
+        x, kept = _period(cfg, params, n, x, mixer)
+        return x, (kept if collect_kv else None)
+
+    if remat:
+        body = jax.checkpoint(body)
+    x, kept = _scan_layers(body, x, _periods(cfg), unroll=cfg.unroll_layers)
+    out = {"logits": _lm_logits(params, cfg, x, logits_for),
+           "aux_loss": jnp.float32(0.0)}
+    if collect_kv:
+        out["state"] = jax.tree.map(_flat, kept["mamba"])  # (Lm, B, ...)
+        out["kv"] = jax.tree.map(_flat, kept["attention"])  # (La, B, S, K, hd)
+    return out
+
+
+def _decode_interleaved(params, cfg, cache, x, lengths, uniform):
+    """The decode step's layers.  Each Mamba layer reads its conv and SSM
+    state from the cache's slabs at its index and writes the new state back
+    there; the slabs ride in the scan's carry, so the update is in place in
+    the donated cache.  The K/V slabs are only read; every attention layer's
+    new k/v is written after the loop (``_append_kv``)."""
+    B = x.shape[0]
+    K, hd = cfg.num_kv_heads, cfg.head_dim
+    angles = (rope.rope_angles(lengths[:, None], cfg.head_dim, cfg.rope_theta)
+              if cfg.rope_theta else None)
+
+    def body(carry, n):
+        x, slabs = carry
+        slabs = dict(slabs)
+
+        def mixer(kind, at, w, xn):
+            if kind == "attention":
+                kc, vc = (jax.lax.dynamic_index_in_dim(
+                    cache[c], at, keepdims=False).reshape(B, -1, K, hd)
+                    for c in ("k", "v"))
+                h, kn, vn = _self_attention_decode(w, cfg, xn, angles, kc, vc,
+                                                   lengths)
+                return h, (kn, vn)
+            h, new = ssm.mamba2_mix(
+                w, xn, head_dim=cfg.ssm_head_dim, ssm_state=cfg.ssm_state,
+                cache=_at(slabs, at))
+            for k, v in slabs.items():
+                slabs[k] = jax.lax.dynamic_update_index_in_dim(
+                    v, new[k].astype(v.dtype), at, 0)
+            return h, None
+
+        x, kept = _period(cfg, params, n, x, mixer)
+        return (x, slabs), kept["attention"]
+
+    slabs = {"conv": cache["conv"], "ssm": cache["ssm"]}
+    (x, slabs), (kn, vn) = _scan_layers(body, (x, slabs), _periods(cfg),
+                                        unroll=cfg.unroll_layers)
+    cache = dict(cache, **slabs)
+    return x, _append_kv(cache, _flat(kn), _flat(vn), lengths, uniform)
 
 
 def _forward_whisper(params, cfg, batch, *, collect_kv=False,
@@ -466,6 +632,30 @@ def _forward_whisper(params, cfg, batch, *, collect_kv=False,
 # =============================================================================
 
 
+# the cache leaves that hold keys and values (and their count); every other
+# leaf is a slot's recurrent state
+KV_LEAVES = ("k", "v", "xk", "xv", "lengths")
+
+
+def cache_bytes(cfg: ModelConfig, max_seq: int):
+    """(bytes of keys and values one position of one slot holds, bytes of
+    recurrent state one slot holds), from ``init_cache``'s shapes."""
+    leaves = jax.eval_shape(lambda: init_cache(cfg, 1, max_seq))
+    nbytes = lambda a: a.size * a.dtype.itemsize  # noqa: E731
+    kv = sum(nbytes(leaves[k]) for k in ("k", "v") if k in leaves)
+    state = sum(nbytes(a) for k, a in leaves.items() if k not in KV_LEAVES)
+    return kv // max_seq, state
+
+
+def _kv_tail(cfg: ModelConfig):
+    """The minor dims of a K/V slab whose layers the decode step reads by
+    reshaping: (K, hd), or (K * hd,) when a head is narrower than the TPU's
+    128 lanes, where the device lays (..., K, hd) out sequence-minor and the
+    new token's write cannot update it in place (``_append_kv``)."""
+    K, hd = cfg.num_kv_heads, cfg.head_dim
+    return (K * hd,) if hd % 128 else (K, hd)
+
+
 def init_cache(cfg: ModelConfig, batch_size: int, max_seq: int, dtype=None):
     """Allocate the decode cache for ``batch_size`` slots of ``max_seq``."""
     dt = dtype or cfg.jnp_dtype
@@ -498,6 +688,18 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_seq: int, dtype=None):
             "v": jnp.zeros((G, B, max_seq, K, hd), dt),
             "lengths": lengths,
         }
+    if cfg.family == "interleaved":
+        La, Lm = cfg.layers_of("attention"), cfg.layers_of("mamba")
+        nh = cfg.d_inner // cfg.ssm_head_dim
+        return {
+            "k": jnp.zeros((La, B, max_seq, *_kv_tail(cfg)), dt),
+            "v": jnp.zeros((La, B, max_seq, *_kv_tail(cfg)), dt),
+            "conv": jnp.zeros((Lm, B, 3, cfg.d_inner + 2 * cfg.ssm_state),
+                              dt),
+            "ssm": jnp.zeros((Lm, B, nh, cfg.ssm_head_dim, cfg.ssm_state),
+                             jnp.float32),
+            "lengths": lengths,
+        }
     if cfg.family == "audio":
         return {
             "k": jnp.zeros((L, B, max_seq, K, hd), dt),
@@ -527,14 +729,17 @@ def prefill(params, cfg: ModelConfig, batch, max_seq: int):
     )
     cache = init_cache(cfg, B, max_seq)
     lengths = jnp.full((B,), S, jnp.int32)
-    if cfg.family in ("dense", "moe", "vlm"):
-        k, v = out["kv"]
+    if cfg.family in ("dense", "moe", "vlm", "interleaved"):
+        k, v = (a.reshape(*a.shape[:3], *cache["k"].shape[3:])
+                for a in out["kv"])
+        at = (0,) * cache["k"].ndim
         cache["k"] = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0, 0)
-        )
+            cache["k"], k.astype(cache["k"].dtype), at)
         cache["v"] = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0, 0)
-        )
+            cache["v"], v.astype(cache["v"].dtype), at)
+    if cfg.family == "interleaved":
+        cache["conv"] = out["state"]["conv"].astype(cache["conv"].dtype)
+        cache["ssm"] = out["state"]["ssm"]
     elif cfg.family == "ssm":
         st = out["state"]
         cache["wkv"] = st["wkv"]
@@ -591,6 +796,8 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions=None,
     lengths = cache["lengths"]
     B = tokens.shape[0]
     x = layers.embed(tokens, params["embed"])[:, None]  # (B,1,D)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     # native sliding window always applies; the long-context window variant
     # only engages for caches past 64k (dense archs stay full-attention at 32k)
     window = cfg.attn_window
@@ -695,6 +902,11 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, positions=None,
             ssm=nhs.reshape(L, *nhs.shape[2:]),
         )
         cache = _append_kv(cache, kn, vn, lengths, uniform_lengths)
+        return _lm_logits(params, cfg, x)[:, 0], cache
+
+    if cfg.family == "interleaved":
+        x, cache = _decode_interleaved(params, cfg, cache, x, lengths,
+                                       uniform_lengths)
         return _lm_logits(params, cfg, x)[:, 0], cache
 
     if cfg.family == "audio":

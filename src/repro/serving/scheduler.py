@@ -29,12 +29,12 @@ import time
 from collections import deque
 from typing import List, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engines import Engine
 from repro.energy.meter import estimate_j_per_token
+from repro.models.transformer import KV_LEAVES
 from repro.serving.core import SchedulerCore, SchedulingPolicy, pad_prompts
 from repro.serving.request import Request, ServingMetrics
 from repro.serving.stepcache import StepTimeCache, shape_bucket, synth_tokens
@@ -224,13 +224,28 @@ class ContinuousBatchPolicy(SchedulingPolicy):
     def active(self, core: SchedulerCore) -> bool:
         return any(r is not None for r in self.slot_req)
 
-    def _insert(self, cache, sub, slot: int):
+    def _insert(self, core: SchedulerCore, sub, slot: int, rid: int) -> None:
+        """Copy a prefilled request's cache (batch 1) into ``slot`` of the
+        slab: its keys and values and their length first, then its
+        recurrent state, if the model keeps any.  Each part replaces its
+        leaves in ``self.kv`` before the next is copied, so at most one
+        part's old leaves are alive beside the new."""
         def put(leaf, s):
             if leaf.ndim == 1:  # lengths (B,)
                 return leaf.at[slot].set(s[0])
             return leaf.at[:, slot].set(s[:, 0])
 
-        return jax.tree.map(put, cache, sub)
+        kv = [k for k in sub if k in KV_LEAVES]
+        state = [k for k in sub if k not in KV_LEAVES]
+        for name, part in (("serve.insert.kv", kv),
+                           ("serve.insert.state", state)):
+            if not part:
+                continue
+            with span(name, rid=rid, slot=slot):
+                self.kv = dict(self.kv, **{k: put(self.kv[k], sub[k])
+                                           for k in part})
+        core.counters["state_bytes_inserted"] += sum(
+            sub[k].nbytes for k in state)
 
     def _admit(self, core: SchedulerCore) -> None:
         with span("serve.admit"):
@@ -274,7 +289,7 @@ class ContinuousBatchPolicy(SchedulingPolicy):
         if out is not None:
             tok, sub = out
             with span("serve.insert", rid=req.rid, slot=s):
-                self.kv = self._insert(self.kv, sub, s)
+                self._insert(core, sub, s, req.rid)
                 self.cur_tok = self.cur_tok.at[s].set(tok[0])
             with span("serve.first_token", rid=req.rid):
                 first = int(tok[0])

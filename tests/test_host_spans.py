@@ -28,7 +28,7 @@ NEWS = (3, 5, 2, 4, 6)
 # retires after step 1, r0 after 2, r1 and r3 after 4; r3 and r4 take the
 # freed slots), then r4 alone for steps 5-7
 HAND = {"decode_steps": 7, "live_slot_steps": 15, "slot_steps": 21,
-        "admissions": 5, "d2h": 5 + 15}
+        "admissions": 5, "d2h": 5 + 15, "state_bytes_inserted": 0}
 
 
 @pytest.fixture(scope="module")

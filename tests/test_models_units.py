@@ -277,3 +277,70 @@ def test_decode_writes_each_slot_in_place(case):
                 np.testing.assert_array_equal(np.delete(now, n, axis=1),
                                               np.delete(was, n, axis=1))
         seqs = [s + [int(t)] for s, t in zip(seqs, jnp.argmax(logits, -1))]
+
+
+# -- Mamba2: the chunked scan (prefill) against the recurrence (decode) ----------
+
+
+@pytest.mark.parametrize("T,chunk", [(8, 8), (37, 8), (64, 16), (5, 16)])
+@pytest.mark.parametrize("carried", [False, True])
+def test_ssd_chunked_matches_the_recurrence(T, chunk, carried):
+    """Whole chunks, a ragged tail, a sequence shorter than a chunk; from a
+    zero state and from a state carried in."""
+    from repro.models.ssm import ssd_chunked, ssd_scan
+
+    B, nh, hd, S = 2, 3, 4, 5
+    k = jax.random.split(KEY(40), 6)
+    x = jax.random.normal(k[0], (B, T, nh, hd))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (B, T, nh)))
+    A = -jnp.exp(jax.random.normal(k[2], (nh,)))
+    Bs = jax.random.normal(k[3], (B, T, S))
+    Cs = jax.random.normal(k[4], (B, T, S))
+    h0 = (jax.random.normal(k[5], (B, nh, hd, S)) if carried
+          else jnp.zeros((B, nh, hd, S)))
+    y_seq, h_seq = ssd_scan(x, dt, A, Bs, Cs, h0)
+    y, h = ssd_chunked(x, dt, A, Bs, Cs, h0, chunk)
+    # float32 sums in another order; outputs reach ~30
+    np.testing.assert_allclose(y, y_seq, atol=1e-4, rtol=1e-5)
+    np.testing.assert_allclose(h, h_seq, atol=1e-4, rtol=1e-5)
+
+
+def test_granite_slot_3_of_4_decodes_as_alone():
+    """A request admitted into slot 3 while slots 0-2 are mid-decode, through
+    the policy's own insert (K/V, then recurrent state), decodes the logits
+    it decodes alone in a cache of one slot."""
+    from repro.models import decode_step, init_cache, prefill
+    from repro.serving.scheduler import ContinuousBatchPolicy
+
+    cfg, max_seq = smoke_variant(get_arch("granite-4.0-h-micro")), 48
+    params = init_params(cfg, KEY(50))
+    step = jax.jit(lambda c, t: decode_step(params, cfg, c, t))
+    prompts = [jax.random.randint(KEY(51 + i), (1, n), 0, cfg.vocab_size)
+               for i, n in enumerate((9, 20, 13, 17))]
+
+    class Core:
+        counters = {"state_bytes_inserted": 0}
+
+    policy = ContinuousBatchPolicy(4, max_seq)
+    policy.kv = init_cache(cfg, 4, max_seq)
+    toks = [0] * 4
+    for s, prompt in enumerate(prompts[:3]):
+        lg, sub = prefill(params, cfg, {"tokens": prompt}, max_seq)
+        policy._insert(Core, sub, s, rid=s)
+        toks[s] = int(jnp.argmax(lg[0]))
+    for _ in range(2):
+        lg, policy.kv = step(policy.kv, jnp.asarray(toks, jnp.int32))
+        toks = [int(t) for t in jnp.argmax(lg, -1)]
+    lg, sub = prefill(params, cfg, {"tokens": prompts[3]}, max_seq)
+    policy._insert(Core, sub, 3, rid=3)
+    toks[3] = int(jnp.argmax(lg[0]))
+    alone, tok = sub, jnp.asarray([toks[3]], jnp.int32)
+    for _ in range(4):
+        lg, policy.kv = step(policy.kv, jnp.asarray(toks, jnp.int32))
+        ref, alone = step(alone, tok)
+        # float32; only the batch differs
+        np.testing.assert_allclose(lg[3], ref[0], atol=1e-5, rtol=1e-5)
+        toks = [int(t) for t in jnp.argmax(lg, -1)]
+        tok = jnp.argmax(ref, -1).astype(jnp.int32)
+    assert Core.counters["state_bytes_inserted"] == 4 * sum(
+        a.nbytes for k, a in sub.items() if k in ("conv", "ssm"))

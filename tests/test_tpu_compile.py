@@ -147,3 +147,54 @@ def test_minitron_4b_decode_writes_kv_in_place(one_chip):
            or (op == "fusion" and ("copy" in name
                                    or "dynamic-update-slice" in name))]
     assert not bad, bad
+
+
+def test_granite_decode_updates_state_in_place(one_chip):
+    """granite-4.0-h-micro uncut, 32 slots of 4096: the donated decode step
+    writes each Mamba layer's new state and each new token's k/v into the
+    donated slabs.  No temporary is as large as the smallest slab (a K or V
+    slab, 512 MiB), and no slab is copied."""
+    cfg = get_arch("granite-4.0-h-micro")
+    B, max_seq = 32, 4096
+
+    params = _on_chip(one_chip, jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = _on_chip(one_chip, jax.eval_shape(
+        lambda: init_cache(cfg, B, max_seq)))
+    fn = jax.jit(lambda p, c, t: decode_step(p, cfg, c, t),
+                 donate_argnums=(1,))
+    compiled = fn.lower(params, cache,
+                        _shape(one_chip, (B,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    nbytes = {k: a.size * a.dtype.itemsize for k, a in cache.items()}
+    assert nbytes["k"] == 2**29 and nbytes["ssm"] > 2.4e9
+    assert mem.temp_size_in_bytes < nbytes["k"], mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= sum(nbytes.values()) - 2 * 4 * B
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES
+    text = compiled.as_text()
+    for name in ("k", "v", "conv", "ssm"):
+        leaf = cache[name]
+        dt = {"bfloat16": "bf16", "float32": "f32"}[str(leaf.dtype)]
+        slab = "%s[%s]" % (dt, ",".join(map(str, leaf.shape)))
+        ops = re.findall(r"%(\S+) = " + re.escape(slab) + r"\{[^}]*\} (\S+)\(",
+                         text)
+        assert ops, f"no {slab} instruction"
+        copies = [(n, op) for n, op in ops
+                  if op == "copy" or (op == "fusion" and "copy" in n)]
+        assert not copies, (name, copies)
+
+
+def test_granite_prefill_fits_one_chip(one_chip):
+    """A 2048-token prompt at published widths: the chunked scan's
+    temporaries, the weights and a one-slot cache of 4096 fit one v5e."""
+    cfg = get_arch("granite-4.0-h-micro")
+    params = _on_chip(one_chip, jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    fn = jax.jit(lambda p, t: prefill(p, cfg, {"tokens": t}, 4096))
+    mem = fn.lower(params, _shape(one_chip, (1, 2048), jnp.int32)
+                   ).compile().memory_analysis()
+    assert mem.argument_size_in_bytes > 6e9     # the weights are all there
+    assert mem.temp_size_in_bytes < 2**30, mem.temp_size_in_bytes
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < V5E_HBM_BYTES
