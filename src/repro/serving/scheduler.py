@@ -275,6 +275,7 @@ class ContinuousBatchPolicy(SchedulingPolicy):
             with span("serve.prefill", rid=req.rid, bucket=bucket, tokens=S):
                 logits, sub = core.engine.prefill_one(prompt[None, :])
                 tok = jnp.argmax(logits, -1).astype(jnp.int32)
+                tok.copy_to_host_async()
             with span("serve.prefill.wait", rid=req.rid):
                 tok.block_until_ready()
             dt = time.perf_counter() - t0     # simlint: allow(wall-clock)
@@ -292,7 +293,7 @@ class ContinuousBatchPolicy(SchedulingPolicy):
                 self._insert(core, sub, s, req.rid)
                 self.cur_tok = self.cur_tok.at[s].set(tok[0])
             with span("serve.first_token", rid=req.rid):
-                first = int(tok[0])
+                first = int(np.asarray(tok)[0])
             core.counters["d2h"] += 1
         else:
             first = int(synth_tokens(req.prompt, 1, core.vocab)[0])
@@ -316,6 +317,11 @@ class ContinuousBatchPolicy(SchedulingPolicy):
                 core.advance_to(nxt.arrival_s)   # idle until next arrival
             return
         rids = [r.rid for r in self.slot_req if r is not None]
+        # synthetic slots read nothing: the step's tokens come to the host
+        # only when a live slot holds real state, in one transfer started
+        # at dispatch
+        real = any(r is not None and not synth
+                   for r, synth in zip(self.slot_req, self.slot_synth))
 
         def thunk():
             # sanctioned measurement closure (see the prefill thunk above)
@@ -323,6 +329,8 @@ class ContinuousBatchPolicy(SchedulingPolicy):
             with span("serve.decode", live=len(rids)):
                 logits, kv = core.engine.decode_batch(self.kv, self.cur_tok)
                 tok = jnp.argmax(logits, -1).astype(jnp.int32)
+                if real:
+                    tok.copy_to_host_async()
             with span("serve.decode.wait"):
                 tok.block_until_ready()
             dt = time.perf_counter() - t0         # simlint: allow(wall-clock)
@@ -339,14 +347,17 @@ class ContinuousBatchPolicy(SchedulingPolicy):
             tok, self.kv = out
             self.cur_tok = tok
         with span("serve.readback", live=len(rids)):
+            host_tok = None
+            if out is not None and real:
+                with span("serve.d2h", live=len(rids)):
+                    host_tok = np.asarray(tok)
+                counters["d2h"] += 1
             for s in range(self.num_slots):
                 req = self.slot_req[s]
                 if req is None:
                     continue
-                if out is not None and not self.slot_synth[s]:
-                    with span("serve.d2h", slot=s, rid=req.rid):
-                        nxt_tok = int(np.asarray(tok[s]))
-                    counters["d2h"] += 1
+                if host_tok is not None and not self.slot_synth[s]:
+                    nxt_tok = int(host_tok[s])
                 else:
                     nxt_tok = int(
                         synth_tokens(req.prompt, self.slot_emitted[s] + 1,

@@ -18,7 +18,7 @@ from repro.serving import api, scheduler
 from repro.serving.core import SchedulerCore
 from repro.serving.request import Request
 from repro.serving.scheduler import ContinuousBatchPolicy
-from repro.serving.stepcache import StepTimeCache, shape_bucket
+from repro.serving.stepcache import StepTimeCache, shape_bucket, synth_tokens
 
 ARCH = "minitron-4b-smoke"
 SLOTS = 3
@@ -28,7 +28,7 @@ NEWS = (3, 5, 2, 4, 6)
 # retires after step 1, r0 after 2, r1 and r3 after 4; r3 and r4 take the
 # freed slots), then r4 alone for steps 5-7
 HAND = {"decode_steps": 7, "live_slot_steps": 15, "slot_steps": 21,
-        "admissions": 5, "d2h": 5 + 15, "state_bytes_inserted": 0}
+        "admissions": 5, "d2h": 5 + 7, "state_bytes_inserted": 0}
 
 
 @pytest.fixture(scope="module")
@@ -100,16 +100,15 @@ def test_one_decode_readback_and_step_span_per_decode_step(traced):
         [3, 3, 3, 3, 1, 1, 1]
 
 
-def test_one_d2h_per_live_slot_per_step_inside_its_readback(traced):
+def test_one_d2h_per_decode_step_inside_its_readback(traced):
     spans = traced["spans"]
     readbacks = _named(spans, "serve.readback")
     d2h = _named(spans, "serve.d2h")
-    assert len(d2h) == HAND["live_slot_steps"]
+    assert len(d2h) == HAND["decode_steps"]
     for rb in readbacks:
         inside = [d for d in d2h if rb[1] <= d[1] and d[2] <= rb[2]]
-        assert len(inside) == rb[3]["live"]
-    assert all(any(rb[1] <= d[1] and d[2] <= rb[2] for rb in readbacks)
-               for d in d2h)
+        assert [d[3]["live"] for d in inside] == [rb[3]["live"]]
+    assert not any("slot" in d[3] or "rid" in d[3] for d in d2h)
     # every retirement sits in a readback, and carries its request
     retire = _named(spans, "serve.retire")
     assert sorted(s[3]["rid"] for s in retire) == list(range(len(LENS)))
@@ -211,6 +210,60 @@ def test_spans_change_no_token_and_no_virtual_time(model, monkeypatch,
     assert replayed.meter.per_request_j == base_replay.meter.per_request_j
     # replayed steps count steps and occupancy, and read nothing back
     assert counters == dict(HAND, admissions=5, d2h=0)
+
+
+class _ReplayBuckets(StepTimeCache):
+    """Replays the prefills of the given buckets and runs every other
+    engine call, measuring nothing into the cache."""
+
+    def __init__(self, buckets):
+        super().__init__()
+        self.buckets = buckets
+
+    def get(self, key):
+        if key[0] == "prefill1" and key[1] in self.buckets:
+            return (0.01,)
+        return None
+
+    def put(self, key, payload):
+        pass
+
+
+def test_replayed_and_real_slots_in_one_step(model, monkeypatch):
+    """Prompts of 5 and 7 tokens (bucket 8) are admitted by a replayed
+    prefill, the rest run: real slots decode as the realtime scheduler
+    does, replayed ones stay synthetic, and only steps with a real slot
+    read their tokens, in one transfer."""
+    cfg, params = model
+    engine = CompiledEngine(cfg, params, max_seq=64)
+    wl = _workload(cfg)
+    greedy = {r.rid: np.asarray(r.tokens) for r in
+              scheduler.RealTimeScheduler(engine).run(_workload(cfg))
+              .responses}
+    seen = []
+
+    def record(name, **args):
+        seen.append((name, args))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(scheduler, "span", record)
+    core = _core(engine, _ReplayBuckets({8}))
+    got = {r.rid: np.asarray(r.tokens) for r in core.run(wl).responses}
+    synthetic = {0, 4}
+    for req in wl:
+        want = synth_tokens(req.prompt, req.max_new_tokens, cfg.vocab_size) \
+            if req.rid in synthetic else greedy[req.rid]
+        np.testing.assert_array_equal(got[req.rid], want,
+                                      err_msg=f"rid={req.rid}")
+    # r1 (5 new tokens) keeps a real slot live through step 4; steps 5-7
+    # hold r4 alone, replayed, and read nothing
+    c = core.counters
+    assert c["decode_steps"] == HAND["decode_steps"]
+    assert c["admissions"] == len(LENS)
+    assert c["d2h"] == 4 + (len(LENS) - len(synthetic))
+    assert [a["live"] for n, a in seen if n == "serve.d2h"] == [3, 3, 3, 3]
+    assert [a["live"] for n, a in seen if n == "serve.readback"] == \
+        [3, 3, 3, 3, 1, 1, 1]
 
 
 def test_deploy_times_its_registry_round_trip(model, tmp_path):
